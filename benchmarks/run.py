@@ -7,7 +7,7 @@ Prints ``name,us_per_call,derived`` CSV rows.
   bench_concurrency        RQ-A (paper §III.A) — per-policy instance counts
   bench_emulation          RQ-B (paper §III.B) — fidelity + emulation speedup
   bench_serving_engine     real-model worker throughput (Fig.2 step 1 rig)
-  bench_kernels            Pallas kernel microbench (interpret) vs oracle
+  bench_kernels            Pallas kernels in the interpreter vs oracle (CPU timings)
   bench_workload_scenarios named traffic shapes + >=1M-request bursty probe
   bench_autoscaler_scenarios autoscaler policy menu vs static replicate
   bench_fault_scenarios    chaos layer: zone outage A/B + retry storm
@@ -20,6 +20,7 @@ import json
 import os
 import sys
 import time
+import traceback
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
@@ -183,7 +184,7 @@ def bench_kernels():
     for name, fn in (
             ("flash_attn_interpret",
              lambda: flash_attention(q, k, v, causal=True, block_q=128,
-                                     block_k=128)),
+                                     block_k=128, interpret=True)),
             ("flash_attn_ref_xla",
              lambda: R.flash_attention_ref(q, k, v, causal=True))):
         fn()
@@ -197,7 +198,7 @@ def bench_kernels():
     A = -jnp.exp(jax.random.normal(ks[0], (128, 16)) * 0.2)
     D = jnp.ones(128)
     t0 = time.perf_counter()
-    mamba_scan(dt, x, Bc, Bc, A, D, chunk=64, block_d=64)
+    mamba_scan(dt, x, Bc, Bc, A, D, chunk=64, block_d=64, interpret=True)
     _row("mamba_scan_interpret", 1e6 * (time.perf_counter() - t0),
          "S=256;DI=128;N=16")
 
@@ -1051,14 +1052,19 @@ def main(argv=None) -> None:
             print(f"no benchmark matches {only!r}\n{_usage()}",
                   file=sys.stderr)
             sys.exit(2)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
+    failed = []
     for b in BENCHES:
         if only and only not in b.__name__:
             continue
         try:
             b()
-        except Exception as e:  # keep the harness robust
+        except Exception as e:  # record the failure, run the other probes
+            traceback.print_exc()
             _row(b.__name__ + "_ERROR", 0.0, repr(e)[:120])
+            failed.append(b.__name__)
     os.makedirs(OUT_DIR, exist_ok=True)
     # REPRO_EVENT_BACKEND suffixes the artifact so CI's per-backend runs
     # of the same bench don't overwrite each other
@@ -1068,6 +1074,9 @@ def main(argv=None) -> None:
     with open(out, "w") as fh:
         json.dump({"filter": only, "backend": backend, "rows": ROWS}, fh,
                   indent=1)
+    if failed:
+        print(f"probes raised: {' '.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

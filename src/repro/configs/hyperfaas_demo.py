@@ -1,9 +1,10 @@
 """Demo "function images" for the HyperFaaS platform experiments.
 
 These are the paper's analogue of user-supplied functions: small, real models
-that workers can actually execute on the CPU device in this container. They are
-NOT part of the assigned-architecture matrix; they drive the serving engine,
-the concurrency study (RQ-A) and the emulation pipeline (RQ-B).
+that workers execute on JAX's default device — the CPU backend in the tests,
+the TPU on a chip. They are NOT part of the assigned-architecture matrix; they
+drive the serving engine, the concurrency study (RQ-A) and the emulation
+pipeline (RQ-B).
 """
 from repro.configs.base import ModelConfig, register
 

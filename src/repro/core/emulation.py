@@ -75,9 +75,9 @@ class MLPWorkerModel:
     fail_rate: float = 0.0
 
     @staticmethod
-    def _fwd(params, x):
-        h = jnp.tanh(x @ params["w1"] + params["b1"])
-        h = jnp.tanh(h @ params["w2"] + params["b2"])
+    def _fwd(params, x, xp=jnp):
+        h = xp.tanh(x @ params["w1"] + params["b1"])
+        h = xp.tanh(h @ params["w2"] + params["b2"])
         return (h @ params["w3"] + params["b3"])[..., 0]
 
     @staticmethod
@@ -116,8 +116,10 @@ class MLPWorkerModel:
                               fail_rate=float(1 - ok.mean()))
 
     def predict(self, feats: np.ndarray, rng: np.random.Generator):
+        # numpy, not jax: the simulator calls this per request, also inside
+        # forked partitions (repro.parallel), which must not touch JAX
         xs = (feats - self.mu) / self.sd
-        ly = float(self._fwd(self.params, jnp.asarray(xs[None]))[0])
+        ly = float(self._fwd(self.params, xs[None], xp=np)[0])
         ly += rng.normal(0, self.resid_std)
         return float(np.exp(ly)), rng.random() >= self.fail_rate
 

@@ -7,7 +7,9 @@ of one kv head are processed together as a [G, hd] tile (q_per_kv x 128 is
 the MXU-friendly packing for GQA decode).
 
 The wrapper passes per-batch ``valid_len`` (= min(pos+1, W); ring buffers are
-fully valid once wrapped) so ring and linear caches share one kernel.
+fully valid once wrapped) so ring and linear caches share one kernel. It is
+scalar-prefetched into SMEM: a rank-1 VMEM block of one element is not a
+legal TPU tile.
 """
 from __future__ import annotations
 
@@ -30,7 +32,7 @@ def _decode_kernel(vl_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    valid_len = vl_ref[0]
+    valid_len = vl_ref[pl.program_id(0)]
     first = j * bw
 
     @pl.when(first < valid_len)
@@ -60,7 +62,7 @@ def _decode_kernel(vl_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      positions: jax.Array, *, ring: bool = False,
-                     block_w: int = 512, interpret: bool = True) -> jax.Array:
+                     block_w: int = 512, interpret: bool) -> jax.Array:
     """q: [B, H, hd]; caches: [B, W, KV, hd]; positions: [B] -> [B, H, hd]."""
     B, W, KV, hd = k_cache.shape
     H = q.shape[1]
@@ -79,21 +81,25 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     vt = v_cache.transpose(0, 2, 1, 3)
 
     kernel = functools.partial(_decode_kernel, scale=hd ** -0.5, bw=bw, nw=nw)
+    from jax.experimental.pallas import tpu as pltpu
     from repro.kernels.flash_attention import _dim_semantics, _vmem
 
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, KV, nw),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, j: (b,)),
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bw, hd), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bw, hd), lambda b, h, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, G, hd), lambda b, h, j, vl: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bw, hd), lambda b, h, j, vl: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, bw, hd), lambda b, h, j, vl: (b, h, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, j, vl: (b, h, 0, 0)),
         scratch_shapes=[_vmem((G,), jnp.float32), _vmem((G,), jnp.float32),
                         _vmem((G, hd), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         compiler_params=_dim_semantics(("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(valid_len, qt, kt, vt)

@@ -79,7 +79,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 512,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """q: [B, S, H, hd]; k, v: [B, S, KV, hd] -> [B, S, H, hd]."""
     B, S, H, hd = q.shape
     KV = k.shape[2]
@@ -128,7 +128,4 @@ def _vmem(shape, dtype):
 
 def _dim_semantics(sem):
     from jax.experimental.pallas import tpu as pltpu
-    try:
-        return pltpu.CompilerParams(dimension_semantics=sem)
-    except TypeError:
-        return pltpu.TPUCompilerParams(dimension_semantics=sem)
+    return pltpu.CompilerParams(dimension_semantics=sem)

@@ -3,51 +3,60 @@
 The ref path materializes a = exp(dt*A) and bx = dt*B*x as [B, S, DI, N] f32
 tensors in HBM (16x the activation size at N=16) — the dominant memory-roofline
 term for the SSM archs. This kernel fuses the whole recurrence: HBM traffic is
-just the [B, S, DI]-sized dt/x/y plus [B, S, N] B/C — the SSM state h [bd, N]
+just the [B, S, DI]-sized dt/x/y plus [B, S, N] B/C — the SSM state h [N, bd]
 never leaves VMEM.
 
 Grid: (batch, d_inner blocks, seq chunks); the chunk dim is sequential with h
-carried in VMEM scratch. Inside a chunk the recurrence is a fori_loop over
-time steps operating on [bd, N] tiles (bd=256 lanes x N=16 sublanes fills the
-VPU; the recurrence is elementwise, not MXU work).
+carried in VMEM scratch. The state is laid out [N, bd] (state on sublanes,
+channels on lanes), so every per-step operand is a lane row or a sublane
+column and no step needs a relayout. Inside a chunk a fori_loop walks groups
+of ``grp`` time steps: each group loads aligned [grp, bd] / [grp, N] tiles
+(a TPU load at a dynamic sublane offset must be tile-aligned), transposes
+the B/C tiles once, and unrolls the ``grp`` recurrence steps statically.
+The recurrence is elementwise VPU work, not MXU work.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _mamba_kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, d_ref, y_ref, h_scr,
-                  *, chunk, bd, n):
+def _mamba_kernel(dt_ref, x_ref, b_ref, c_ref, at_ref, d_ref, y_ref, h_scr,
+                  y_scr, *, chunk, grp):
     j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    A = a_ref[...].astype(jnp.float32)                       # [bd, N]
-    D = d_ref[...].astype(jnp.float32)                       # [bd]
+    At = at_ref[...].astype(jnp.float32)                     # [N, bd]
+    D = d_ref[...].astype(jnp.float32)                       # [1, bd]
 
-    def step(t, h):
-        dt = dt_ref[0, t].astype(jnp.float32)                # [bd]
-        x = x_ref[0, t].astype(jnp.float32)                  # [bd]
-        Bc = b_ref[0, t].astype(jnp.float32)                 # [N]
-        Cc = c_ref[0, t].astype(jnp.float32)                 # [N]
-        a = jnp.exp(dt[:, None] * A)                         # [bd, N]
-        h = a * h + (dt * x)[:, None] * Bc[None, :]
-        y = jnp.sum(h * Cc[None, :], axis=1) + D * x         # [bd]
-        y_ref[0, t] = y.astype(y_ref.dtype)
+    def group(g, h):
+        base = pl.multiple_of(g * grp, grp)
+        rows = pl.ds(base, grp)
+        dt = dt_ref[0, rows, :].astype(jnp.float32)          # [grp, bd]
+        x = x_ref[0, rows, :].astype(jnp.float32)
+        bT = b_ref[0, rows, :].astype(jnp.float32).T         # [N, grp]
+        cT = c_ref[0, rows, :].astype(jnp.float32).T
+        for i in range(grp):
+            dt_i, x_i = dt[i:i + 1], x[i:i + 1]              # [1, bd]
+            h = jnp.exp(dt_i * At) * h + (dt_i * x_i) * bT[:, i:i + 1]
+            y_scr[i:i + 1, :] = (jnp.sum(h * cT[:, i:i + 1], axis=0,
+                                         keepdims=True) + D * x_i)
+        y_ref[0, rows, :] = y_scr[...].astype(y_ref.dtype)
         return h
 
-    h_scr[...] = jax.lax.fori_loop(0, chunk, step, h_scr[...])
+    h_scr[...] = jax.lax.fori_loop(0, chunk // grp, group, h_scr[...])
 
 
 def mamba_scan(dt: jax.Array, x: jax.Array, B: jax.Array, C: jax.Array,
                A: jax.Array, D: jax.Array, *, chunk: int = 256,
-               block_d: int = 256, interpret: bool = True) -> jax.Array:
+               block_d: int = 256, interpret: bool) -> jax.Array:
     """dt, x: [Bt, S, DI]; B, C: [Bt, S, N]; A: [DI, N]; D: [DI] -> y [Bt, S, DI].
 
     y_t = C_t · h_t + D*x_t  with  h_t = exp(dt_t A) h_{t-1} + dt_t B_t x_t.
@@ -62,7 +71,8 @@ def mamba_scan(dt: jax.Array, x: jax.Array, B: jax.Array, C: jax.Array,
         bd //= 2
     nc, nd = S // ch, DI // bd
 
-    kernel = functools.partial(_mamba_kernel, chunk=ch, bd=bd, n=N)
+    grp = math.gcd(ch, 16)
+    kernel = functools.partial(_mamba_kernel, chunk=ch, grp=grp)
     from repro.kernels.flash_attention import _dim_semantics, _vmem
 
     return pl.pallas_call(
@@ -73,12 +83,13 @@ def mamba_scan(dt: jax.Array, x: jax.Array, B: jax.Array, C: jax.Array,
             pl.BlockSpec((1, ch, bd), lambda b, d, j: (b, j, d)),   # x
             pl.BlockSpec((1, ch, N), lambda b, d, j: (b, j, 0)),    # B
             pl.BlockSpec((1, ch, N), lambda b, d, j: (b, j, 0)),    # C
-            pl.BlockSpec((bd, N), lambda b, d, j: (d, 0)),          # A
-            pl.BlockSpec((bd,), lambda b, d, j: (d,)),              # D
+            pl.BlockSpec((N, bd), lambda b, d, j: (0, d)),          # A^T [N, DI]
+            pl.BlockSpec((1, bd), lambda b, d, j: (0, d)),          # D [1, DI]
         ],
         out_specs=pl.BlockSpec((1, ch, bd), lambda b, d, j: (b, j, d)),
         out_shape=jax.ShapeDtypeStruct((Bt, S, DI), x.dtype),
-        scratch_shapes=[_vmem((bd, N), jnp.float32)],
+        scratch_shapes=[_vmem((N, bd), jnp.float32),
+                        _vmem((grp, bd), jnp.float32)],
         compiler_params=_dim_semantics(("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(dt, x, B, C, A, D)
+    )(dt, x, B, C, A.T, D.reshape(1, DI))

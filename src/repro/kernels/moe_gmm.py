@@ -37,7 +37,7 @@ def _gmm_kernel(bmap_ref, x_ref, w_ref, y_ref, acc_scr, *, nk):
 
 def grouped_matmul(x: jax.Array, w: jax.Array, block_to_expert: jax.Array, *,
                    block_t: int = 128, block_f: int = 128, block_d: int = 512,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool) -> jax.Array:
     """x: [T_pad, D] expert-sorted rows; w: [E, D, F];
     block_to_expert: [T_pad // block_t] int32 -> y [T_pad, F]."""
     T, D = x.shape

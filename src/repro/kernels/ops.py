@@ -1,11 +1,14 @@
 """Jit'd dispatch wrappers around the Pallas kernels.
 
 ``impl`` selection:
-* "pallas"    — pl.pallas_call, interpret=False (real TPU)
-* "interpret" — pl.pallas_call, interpret=True (CPU validation; default here)
-* "ref"       — pure-jnp oracle (what the dry-run lowers)
+* "pallas"    — pl.pallas_call compiled by Mosaic (TPU only)
+* "interpret" — pl.pallas_call in the Pallas interpreter (any backend; how
+  the CPU tests check the kernels)
+* "ref"       — pure-jnp oracle
 
-The model layer calls these when constructed with attn_impl="pallas".
+The caller's ``impl`` is the mode that runs; nothing overrides it. The model
+layer calls these when constructed with ``attn_impl="pallas"`` or
+``"interpret"``.
 """
 from __future__ import annotations
 
@@ -18,14 +21,20 @@ from repro.kernels.flash_attention import flash_attention as _flash_pallas
 from repro.kernels.mamba_scan import mamba_scan as _mamba_pallas
 from repro.kernels.moe_gmm import grouped_matmul as _gmm_pallas
 
-_INTERPRET_DEFAULT = True   # this container is CPU-only; TPU deploys set False
+IMPLS = ("pallas", "interpret", "ref")
+
+
+def _interpret(impl: str) -> bool:
+    if impl not in IMPLS:
+        raise ValueError(f"impl must be one of {IMPLS}, got {impl!r}")
+    return impl == "interpret"
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, impl="interpret"):
     if impl == "ref":
         return _ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     return _flash_pallas(q, k, v, causal=causal, window=window,
-                         interpret=(impl != "pallas") or _INTERPRET_DEFAULT)
+                         interpret=_interpret(impl))
 
 
 def decode_attention(q, k_cache, v_cache, positions, *, ring=False,
@@ -33,21 +42,20 @@ def decode_attention(q, k_cache, v_cache, positions, *, ring=False,
     if impl == "ref":
         return _ref.decode_attention_ref(q, k_cache, v_cache, positions, ring=ring)
     return _decode_pallas(q, k_cache, v_cache, positions, ring=ring,
-                          interpret=(impl != "pallas") or _INTERPRET_DEFAULT)
+                          interpret=_interpret(impl))
 
 
 def mamba_scan(dt, x, B, C, A, D, *, impl="interpret"):
     if impl == "ref":
         return _ref.mamba_scan_ref(dt, x, B, C, A, D)
-    return _mamba_pallas(dt, x, B, C, A, D,
-                         interpret=(impl != "pallas") or _INTERPRET_DEFAULT)
+    return _mamba_pallas(dt, x, B, C, A, D, interpret=_interpret(impl))
 
 
 def grouped_matmul(x, w, block_to_expert, *, block_t=128, impl="interpret"):
     if impl == "ref":
         return _ref.grouped_matmul_ref(x, w, block_to_expert, block_t)
     return _gmm_pallas(x, w, block_to_expert, block_t=block_t,
-                       interpret=(impl != "pallas") or _INTERPRET_DEFAULT)
+                       interpret=_interpret(impl))
 
 
 def moe_expert_ffn(xin, wg, wi, wo, block_to_expert=None, *, impl="interpret"):

@@ -11,6 +11,7 @@ from repro.core.config_store import ConfigStore, ImageRegistry
 from repro.core.router import build_tree
 from repro.core.simulator import summarize
 from repro.core.types import FunctionConfig, Request
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.engine import Engine
 
 
@@ -24,6 +25,7 @@ def main(argv=None):
     ap.add_argument("--policy", default="least_loaded")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     store = ConfigStore()
     store.put(FunctionConfig(name="fn", arch=args.fn_arch,
                              concurrency=args.concurrency,

@@ -13,8 +13,9 @@ plain-einsum oracle in ``repro.kernels.ref``:
 * :func:`attend_decode` — one-token GQA attention against a (possibly
   ring-buffered) KV cache.
 
-On real TPU the Pallas kernels in ``repro.kernels`` replace the first and
-third paths (``impl="pallas"``); the dry-run keeps ``ref`` so cost analysis
+The Pallas kernels in ``repro.kernels`` replace the first and third paths
+with ``impl="pallas"`` (compiled, TPU) or ``impl="interpret"`` (the Pallas
+interpreter, any backend); the dry-run keeps ``ref`` so cost analysis
 reflects the XLA program actually being lowered for the mesh.
 """
 from __future__ import annotations
@@ -69,9 +70,10 @@ def attend_blocked(q: jax.Array, k: jax.Array, v: jax.Array, *,
     [bq, bk] probability block of the forward scan (which would cost
     O(S²/block) residual memory and defeat the whole construction).
     """
-    if impl == "pallas":  # TPU path (validated separately in interpret mode)
+    if impl != "ref":  # Pallas kernel, compiled ("pallas") or interpreted
         from repro.kernels import ops
-        return ops.flash_attention(q, k, v, causal=causal, window=window)
+        return ops.flash_attention(q, k, v, causal=causal, window=window,
+                                   impl=impl)
 
     B, S, H, hd = q.shape
     block = min(block, S)
@@ -241,9 +243,10 @@ def attend_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     slot s holds absolute token  pos - ((pos - s) mod W)  and every slot
     written so far is in-window by construction.
     """
-    if impl == "pallas":
+    if impl != "ref":
         from repro.kernels import ops
-        return ops.decode_attention(q, k_cache, v_cache, positions, ring=ring)
+        return ops.decode_attention(q, k_cache, v_cache, positions, ring=ring,
+                                    impl=impl)
 
     B, W, KV, hd = k_cache.shape
     H = q.shape[1]

@@ -65,7 +65,9 @@ def init_param(spec: ParamSpec, rng, dtype) -> jax.Array:
 
 
 def build_params(specs, rng, dtype):
-    """Materialize a ParamSpec pytree into real arrays (reduced configs only)."""
+    """Materialize a ParamSpec pytree into real arrays. Jit it at real widths
+    (``serving.engine.image_params`` does): then each leaf is drawn and cast
+    inside one program and no f32 copy of a leaf is ever resident."""
     leaves, treedef = jax.tree.flatten(specs, is_leaf=lambda x: isinstance(x, ParamSpec))
     rngs = jax.random.split(rng, len(leaves))
     return jax.tree.unflatten(treedef, [init_param(s, r, dtype) for s, r in zip(leaves, rngs)])

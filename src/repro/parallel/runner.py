@@ -12,7 +12,12 @@ by construction:
 - ``process``  — each partition runs in a forked worker process and the
   coordinator speaks a small message protocol over a pipe. The *fork*
   start method is required: the builder closure is inherited, never
-  pickled.
+  pickled. A forked partition never touches JAX: the simulator, its
+  workloads, autoscaler, gateway and fault layers are numpy/Python, and
+  the one service model with a jax fit (``MLPWorkerModel``) predicts in
+  numpy. That matters on a TPU host, where the chip belongs to the
+  process that first touched JAX; a ``build`` callback must keep to the
+  same rule.
 
 Two synchronization regimes:
 

@@ -7,13 +7,15 @@ prefill/decode, SlotCache). Cold start = param materialization + first-shape
 jit, measured with a wall clock and charged to the triggering request — the
 HyperFaaS analogue of a container pull + boot.
 
-The :class:`Engine` glues a router tree over N workers in one process. It is
-intentionally synchronous and deterministic (single CPU device); massive-load
+The :class:`Engine` glues a router tree over N workers in one process, all on
+JAX's default device (the TPU where there is one; the CPU backend in the
+tests). It is intentionally synchronous and deterministic; massive-load
 studies use the simulator with workers emulated from THIS engine's telemetry.
 """
 from __future__ import annotations
 
 import time
+import zlib
 from collections import deque
 from dataclasses import dataclass
 from random import Random
@@ -44,6 +46,19 @@ def _bucket(n: int) -> int:
 _IMAGE_CACHE: Dict[tuple, tuple] = {}
 
 
+def image_params(model, arch: str):
+    """An image's weights: random, seeded by a stable digest of ``arch`` (the
+    same in every process), drawn by one jitted program so each leaf lands in
+    the model dtype without an f32 copy of the whole tree."""
+    seed = zlib.crc32(arch.encode()) % 2**31
+    return jax.jit(model.init_params)(jax.random.PRNGKey(seed))
+
+
+def image_programs(model):
+    """The jitted (prefill, decode) programs every instance of ``model`` runs."""
+    return jax.jit(model.prefill), jax.jit(model.decode_step)
+
+
 class Instance:
     def __init__(self, iid: str, cfg: FunctionConfig, *, rng_seed: int = 0,
                  max_len: int = 256):
@@ -56,9 +71,8 @@ class Instance:
         if key not in _IMAGE_CACHE:
             mcfg = get_config(cfg.arch)
             model = build_model(mcfg)
-            params = model.init_params(jax.random.PRNGKey(hash(cfg.arch) % 2**31))
-            prefill = jax.jit(lambda p, b: model.prefill(p, b))
-            decode = jax.jit(lambda p, c, b: model.decode_step(p, c, b))
+            params = image_params(model, cfg.arch)
+            prefill, decode = image_programs(model)
             # shape warmup = the dominant cold-start cost (compile)
             kv0 = SlotCache(model, slots, max_len)
             warm = {"tokens": jnp.zeros((1, 16), jnp.int32)}
@@ -158,7 +172,8 @@ class Worker:
             logits, pcache = inst._prefill(inst.params,
                                            {"tokens": jnp.asarray(toks)})
             jax.block_until_ready(logits)
-            inst.kv.admit(slot, pcache, bl, p.req.rid, cfg.gen_tokens)
+            # prefill yields the first of the request's gen_tokens tokens
+            inst.kv.admit(slot, pcache, bl, p.req.rid, cfg.gen_tokens - 1)
             inst._last_tok[slot] = int(jnp.argmax(logits[0]))
             inst.generated[p.req.rid] = [int(inst._last_tok[slot])]
             inst.last_used = time.monotonic()
@@ -173,13 +188,12 @@ class Worker:
             p._instance = inst
             p._slot = slot
             p._cold = cold
-            if not hasattr(inst, "_slot_meta"):
-                inst._slot_meta = {}
             inst._slot_meta[slot] = p
         self.pending = still
         # decode step per instance
-        for fn, il in self.instances.items():
+        for il in self.instances.values():
             for inst in il:
+                self._complete(inst, results)   # requests done at prefill
                 if inst.busy() == 0:
                     continue
                 tok = jnp.asarray(inst._last_tok)
@@ -196,18 +210,21 @@ class Worker:
                             inst.generated[rid].append(int(nxt[s]))
                 inst.kv.advance()
                 inst.last_used = time.monotonic()
-                for slot in inst.kv.finished_slots():
-                    p = inst._slot_meta.pop(slot)
-                    inst.kv.release(slot)
-                    now = time.monotonic()
-                    rec = self.telemetry[p._telemetry_idx]
-                    rec.latency = now - p.submit_t
-                    results.append(RequestResult(
-                        rid=p.req.rid, fn=p.req.fn, ok=True,
-                        arrival_t=p.submit_t, start_t=p.submit_t,
-                        finish_t=now, cold_start=p._cold,
-                        worker=self.name, instance=inst.iid))
+                self._complete(inst, results)
         return results
+
+    def _complete(self, inst: Instance, results: List[RequestResult]):
+        for slot in inst.kv.finished_slots():
+            p = inst._slot_meta.pop(slot)
+            inst.kv.release(slot)
+            now = time.monotonic()
+            rec = self.telemetry[p._telemetry_idx]
+            rec.latency = now - p.submit_t
+            results.append(RequestResult(
+                rid=p.req.rid, fn=p.req.fn, ok=True,
+                arrival_t=p.submit_t, start_t=p.submit_t,
+                finish_t=now, cold_start=p._cold,
+                worker=self.name, instance=inst.iid))
 
     def drain(self) -> List[RequestResult]:
         out = []

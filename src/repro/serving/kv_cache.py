@@ -28,8 +28,9 @@ class SlotCache:
         return [i for i in range(self.slots) if not self.active[i]]
 
     def admit(self, slot: int, prefill_cache, prompt_len: int, rid: int,
-              gen_tokens: int):
-        """Insert a prefilled (batch=1) sequence into `slot`."""
+              decode_steps: int):
+        """Insert a prefilled (batch=1) sequence into `slot`; it finishes after
+        ``decode_steps`` more decode steps."""
         def insert(c, p):
             # c: [K, slots, W, ...] or [K, slots, ...]; p batch dim = 1
             if c.ndim >= 3 and p.shape[2] != c.shape[2] and p.ndim == c.ndim:
@@ -45,7 +46,7 @@ class SlotCache:
         self.pos[slot] = prompt_len
         self.active[slot] = True
         self.rid[slot] = rid
-        self.remaining[slot] = gen_tokens
+        self.remaining[slot] = decode_steps
 
     def release(self, slot: int):
         self.active[slot] = False

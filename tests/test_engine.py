@@ -1,4 +1,8 @@
 """Real serving engine: actual JAX execution, continuous batching, cold starts."""
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -105,3 +109,49 @@ def test_telemetry_recorded(engine):
     assert tel
     t = tel[-1]
     assert t.latency > 0 and t.fn == "gen" and len(t.features()) == 7
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("gen", [1, 3])
+def test_request_generates_gen_tokens(platform, gen):
+    """The prefill token counts: a request gets exactly gen_tokens tokens."""
+    store, registry = platform
+    fn = f"gen{gen}"
+    store.put(FunctionConfig(name=fn, arch="tiny_lm", concurrency=2,
+                             gen_tokens=gen, idle_timeout_s=60.0))
+    w = Worker(f"w-{fn}", store, registry, max_len=64)
+    reqs = [Request(fn=fn, arrival_t=0.0, size=8) for _ in range(3)]
+    for r in reqs:
+        w.submit(r)
+    results = w.drain()
+    assert sorted(r.rid for r in results) == sorted(r.rid for r in reqs)
+    generated = {rid: toks for inst in w.instances[fn]
+                 for rid, toks in inst.generated.items()}
+    assert [len(generated[r.rid]) for r in reqs] == [gen] * len(reqs)
+    assert all(inst.busy() == 0 for inst in w.instances[fn])
+
+
+_WEIGHT_DIGEST = """
+import hashlib, numpy as np, jax
+from repro.models import build_model
+from repro.configs import get_config
+from repro.serving.engine import image_params
+p = image_params(build_model(get_config("tiny_lm")), "tiny_lm")
+h = hashlib.sha256()
+for leaf in jax.tree.leaves(p):
+    h.update(np.asarray(leaf, np.float32).tobytes())
+print(h.hexdigest())
+"""
+
+
+def test_image_weights_same_in_every_process():
+    """Weights seed from a stable digest of the arch, not Python's salted
+    hash(): two processes with different hash seeds draw the same image."""
+    digests = set()
+    for hash_seed in ("1", "2"):
+        env = dict(os.environ, PYTHONHASHSEED=hash_seed, JAX_PLATFORMS="cpu")
+        out = subprocess.run([sys.executable, "-c", _WEIGHT_DIGEST], env=env,
+                             capture_output=True, text=True, timeout=300,
+                             check=True)
+        digests.add(out.stdout.strip())
+    assert len(digests) == 1

@@ -1,5 +1,6 @@
-"""Pallas kernels (interpret=True) vs pure-jnp oracles, swept over
-shapes/dtypes as required by the deliverables."""
+"""Pallas kernels in the Pallas interpreter (interpret=True) vs pure-jnp
+oracles, swept over shapes/dtypes. The compiled TPU lowering of the same
+kernels is checked in test_tpu_compile.py."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -33,7 +34,7 @@ def test_flash_attention_sweep(B, S, H, KV, hd, causal, window, dtype, rng):
     k = jax.random.normal(ks[1], (B, S, KV, hd), dtype)
     v = jax.random.normal(ks[2], (B, S, KV, hd), dtype)
     out = flash_attention(q, k, v, causal=causal, window=window,
-                          block_q=64, block_k=128)
+                          block_q=64, block_k=128, interpret=True)
     ref = R.flash_attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
@@ -52,7 +53,8 @@ def test_decode_attention_sweep(B, W, H, KV, hd, ring, dtype, rng):
     kc = jax.random.normal(ks[1], (B, W, KV, hd), dtype)
     vc = jax.random.normal(ks[2], (B, W, KV, hd), dtype)
     pos = jax.random.randint(ks[3], (B,), 5, W * 2 if ring else W)
-    out = decode_attention(q, kc, vc, pos, ring=ring, block_w=64)
+    out = decode_attention(q, kc, vc, pos, ring=ring, block_w=64,
+                           interpret=True)
     ref = R.decode_attention_ref(q, kc, vc, pos, ring=ring)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
@@ -73,7 +75,8 @@ def test_mamba_scan_sweep(B, S, DI, N, chunk, bd, dtype, rng):
     Cc = jax.random.normal(ks[3], (B, S, N), dtype)
     A = -jnp.exp(jax.random.normal(ks[4], (DI, N)) * 0.2)
     D = jax.random.normal(ks[5], (DI,))
-    y = mamba_scan(dt, x, Bc, Cc, A, D, chunk=chunk, block_d=bd)
+    y = mamba_scan(dt, x, Bc, Cc, A, D, chunk=chunk, block_d=bd,
+                   interpret=True)
     ref = R.mamba_scan_ref(dt, x, Bc, Cc, A, D)
     tol = dict(rtol=5e-2, atol=5e-2) if dtype == jnp.bfloat16 \
         else dict(rtol=2e-3, atol=2e-3)
@@ -92,7 +95,7 @@ def test_grouped_matmul_sweep(T, D, F, E, bt, dtype, rng):
     x = jax.random.normal(ks[0], (T, D), dtype)
     w = jax.random.normal(ks[1], (E, D, F), dtype)
     bmap = jax.random.randint(ks[2], (T // bt,), 0, E).astype(jnp.int32)
-    y = grouped_matmul(x, w, bmap, block_t=bt)
+    y = grouped_matmul(x, w, bmap, block_t=bt, interpret=True)
     ref = R.grouped_matmul_ref(x, w, bmap, bt)
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
@@ -102,5 +105,6 @@ def test_flash_kernel_is_tiled():
     """BlockSpec tiling: odd block sizes halve down to divide S."""
     q = jnp.zeros((1, 96, 2, 32), jnp.float32)
     k = jnp.zeros((1, 96, 1, 32), jnp.float32)
-    out = flash_attention(q, k, k, causal=True, block_q=64, block_k=64)
+    out = flash_attention(q, k, k, causal=True, block_q=64, block_k=64,
+                          interpret=True)
     assert out.shape == (1, 96, 2, 32)
