@@ -24,6 +24,7 @@ from typing import Dict, List
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import get_config
 from repro.core.config_store import ConfigStore, ImageRegistry
@@ -111,7 +112,6 @@ class Worker:
         self.instances: Dict[str, List[Instance]] = {}
         self.pending: deque = deque()
         self.telemetry: List[TelemetryRecord] = []
-        self.cold_starts = 0
         self._iid = 0
 
     # ------------------------------------------------------------- state
@@ -124,22 +124,27 @@ class Worker:
             warm_fns=frozenset(fn for fn, il in self.instances.items() if il))
 
     def submit(self, req: Request):
-        self.pending.append(_Pending(req, time.monotonic()))
+        with TraceAnnotation("engine.enqueue", rid=req.rid):
+            self.pending.append(_Pending(req, time.monotonic()))
 
     # ---------------------------------------------------------- lifecycle
+    def _has_room(self, cfg: FunctionConfig) -> bool:
+        il = self.instances.get(cfg.name, [])
+        return (any(inst.kv.free_slots() for inst in il)
+                or len(il) < cfg.max_instances_per_worker)
+
     def _get_instance(self, cfg: FunctionConfig):
+        """An instance of ``cfg`` with a free slot, cold-starting one where
+        none has one; the caller has checked :meth:`_has_room`."""
         il = self.instances.setdefault(cfg.name, [])
         for inst in il:
             if inst.kv.free_slots():
                 return inst, False
-        if len(il) < cfg.max_instances_per_worker:
-            self._iid += 1
-            inst = Instance(f"{self.name}/i{self._iid}", cfg,
-                            rng_seed=self._iid, max_len=self.max_len)
-            il.append(inst)
-            self.cold_starts += 1
-            return inst, True
-        return None, False
+        self._iid += 1
+        inst = Instance(f"{self.name}/i{self._iid}", cfg,
+                        rng_seed=self._iid, max_len=self.max_len)
+        il.append(inst)
+        return inst, True
 
     def reap_idle(self):
         now = time.monotonic()
@@ -153,54 +158,67 @@ class Worker:
     def step(self) -> List[RequestResult]:
         """Admit pending into slots, run ONE decode step on every instance
         with active slots, and complete finished sequences."""
-        results = []
-        # admission
-        still = deque()
-        while self.pending:
-            p = self.pending.popleft()
-            cfg = self.store.get(p.req.fn)
-            inst, cold = self._get_instance(cfg)
-            if inst is None:
-                still.append(p)
-                continue
-            slot = inst.kv.free_slots()[0]
-            bl = _bucket(p.req.size)
-            toks = np.zeros((1, bl), np.int32)
-            payload = np.asarray(p.req.payload if p.req.payload is not None
-                                 else np.arange(p.req.size) % 97 + 2)
-            toks[0, :p.req.size] = payload[:p.req.size]
+        with TraceAnnotation("engine.step"):
+            results = []
+            still = deque()
+            while self.pending:
+                p = self.pending.popleft()
+                cfg = self.store.get(p.req.fn)
+                if not self._has_room(cfg):
+                    still.append(p)
+                    continue
+                with TraceAnnotation("engine.admit", rid=p.req.rid):
+                    self._admit(p, cfg)
+            self.pending = still
+            for il in self.instances.values():
+                for inst in il:
+                    self._complete(inst, results)   # requests done at prefill
+                    if inst.busy():
+                        self._decode_instance(inst)
+                        self._complete(inst, results)
+            return results
+
+    def _admit(self, p: _Pending, cfg: FunctionConfig):
+        """Prefill ``p`` into a free slot and take its first token."""
+        inst, cold = self._get_instance(cfg)
+        rid = p.req.rid
+        slot = inst.kv.free_slots()[0]
+        bl = _bucket(p.req.size)
+        toks = np.zeros((1, bl), np.int32)
+        payload = np.asarray(p.req.payload if p.req.payload is not None
+                             else np.arange(p.req.size) % 97 + 2)
+        toks[0, :p.req.size] = payload[:p.req.size]
+        with TraceAnnotation("engine.prefill", rid=rid):
             logits, pcache = inst._prefill(inst.params,
                                            {"tokens": jnp.asarray(toks)})
             jax.block_until_ready(logits)
-            # prefill yields the first of the request's gen_tokens tokens
-            inst.kv.admit(slot, pcache, bl, p.req.rid, cfg.gen_tokens - 1)
+        # prefill yields the first of the request's gen_tokens tokens
+        inst.kv.admit(slot, pcache, bl, rid, cfg.gen_tokens - 1)
+        with TraceAnnotation("engine.first_token", rid=rid):
             inst._last_tok[slot] = int(jnp.argmax(logits[0]))
-            inst.generated[p.req.rid] = [int(inst._last_tok[slot])]
-            inst.last_used = time.monotonic()
-            self.telemetry.append(TelemetryRecord(
-                fn=p.req.fn, t=p.submit_t, queue_len=len(self.pending),
-                inflight=inst.busy() - 1, batch_size=inst.busy(),
-                cold=cold, prompt_tokens=p.req.size,
-                gen_tokens=cfg.gen_tokens,
-                fn_cost=get_config(cfg.arch).param_count() / 1e7,
-                latency=0.0, ok=True))
-            p._telemetry_idx = len(self.telemetry) - 1
-            p._instance = inst
-            p._slot = slot
-            p._cold = cold
-            inst._slot_meta[slot] = p
-        self.pending = still
-        # decode step per instance
-        for il in self.instances.values():
-            for inst in il:
-                self._complete(inst, results)   # requests done at prefill
-                if inst.busy() == 0:
-                    continue
-                tok = jnp.asarray(inst._last_tok)
-                logits, inst.kv.cache = inst._decode(
-                    inst.params, inst.kv.cache,
-                    {"token": tok, "pos": inst.kv.positions()})
-                jax.block_until_ready(logits)
+        inst.generated[rid] = [int(inst._last_tok[slot])]
+        inst.last_used = time.monotonic()
+        self.telemetry.append(TelemetryRecord(
+            fn=p.req.fn, t=p.submit_t, queue_len=len(self.pending),
+            inflight=inst.busy() - 1, batch_size=inst.busy(),
+            cold=cold, prompt_tokens=p.req.size,
+            gen_tokens=cfg.gen_tokens,
+            fn_cost=get_config(cfg.arch).param_count() / 1e7,
+            latency=0.0, ok=True))
+        p._telemetry_idx = len(self.telemetry) - 1
+        p._cold = cold
+        inst._slot_meta[slot] = p
+
+    def _decode_instance(self, inst: Instance):
+        """One decode step over every slot of ``inst``, feeding each active
+        slot's greedy token back for the next."""
+        with TraceAnnotation("engine.decode"):
+            tok = jnp.asarray(inst._last_tok)
+            logits, inst.kv.cache = inst._decode(
+                inst.params, inst.kv.cache,
+                {"token": tok, "pos": inst.kv.positions()})
+            jax.block_until_ready(logits)
+            with TraceAnnotation("engine.sample"):
                 nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
                 for s in range(inst.slots):
                     if inst.kv.active[s]:
@@ -209,22 +227,21 @@ class Worker:
                         if rid in inst.generated:
                             inst.generated[rid].append(int(nxt[s]))
                 inst.kv.advance()
-                inst.last_used = time.monotonic()
-                self._complete(inst, results)
-        return results
+        inst.last_used = time.monotonic()
 
     def _complete(self, inst: Instance, results: List[RequestResult]):
         for slot in inst.kv.finished_slots():
             p = inst._slot_meta.pop(slot)
-            inst.kv.release(slot)
-            now = time.monotonic()
-            rec = self.telemetry[p._telemetry_idx]
-            rec.latency = now - p.submit_t
-            results.append(RequestResult(
-                rid=p.req.rid, fn=p.req.fn, ok=True,
-                arrival_t=p.submit_t, start_t=p.submit_t,
-                finish_t=now, cold_start=p._cold,
-                worker=self.name, instance=inst.iid))
+            with TraceAnnotation("engine.complete", rid=p.req.rid):
+                inst.kv.release(slot)
+                now = time.monotonic()
+                rec = self.telemetry[p._telemetry_idx]
+                rec.latency = now - p.submit_t
+                results.append(RequestResult(
+                    rid=p.req.rid, fn=p.req.fn, ok=True,
+                    arrival_t=p.submit_t, start_t=p.submit_t,
+                    finish_t=now, cold_start=p._cold,
+                    worker=self.name, instance=inst.iid))
 
     def drain(self) -> List[RequestResult]:
         out = []
@@ -249,7 +266,8 @@ class Engine:
             self.view.update(w.state())
 
     def submit(self, req: Request):
-        wid, _ = self.tree.route(req, self.view, self.rng, time.monotonic())
+        with TraceAnnotation("engine.route", rid=req.rid):
+            wid, _ = self.tree.route(req, self.view, self.rng, time.monotonic())
         self.workers[wid].submit(req)
         self.view.update(self.workers[wid].state())
 

@@ -11,6 +11,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 class SlotCache:
@@ -42,18 +43,21 @@ class SlotCache:
                     slot, axis=1)
             return jax.lax.dynamic_update_slice_in_dim(
                 c, p.astype(c.dtype), slot, axis=1)
-        self.cache = jax.tree.map(insert, self.cache, prefill_cache)
-        self.pos[slot] = prompt_len
-        self.active[slot] = True
-        self.rid[slot] = rid
-        self.remaining[slot] = decode_steps
+        with TraceAnnotation("engine.insert", rid=rid):
+            self.cache = jax.tree.map(insert, self.cache, prefill_cache)
+            self.pos[slot] = prompt_len
+            self.active[slot] = True
+            self.rid[slot] = rid
+            self.remaining[slot] = decode_steps
 
     def release(self, slot: int):
         self.active[slot] = False
         self.rid[slot] = -1
 
     def positions(self) -> jnp.ndarray:
-        return jnp.asarray(self.pos)
+        """The next position of every slot, as a copy: an :meth:`advance`
+        after this call does not change what it returned."""
+        return jnp.asarray(self.pos.copy())
 
     def advance(self):
         self.pos[self.active] += 1

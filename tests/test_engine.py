@@ -155,3 +155,22 @@ def test_image_weights_same_in_every_process():
                              check=True)
         digests.add(out.stdout.strip())
     assert len(digests) == 1
+
+
+def test_positions_are_a_copy():
+    """An advance() after positions() leaves what it returned unchanged. On
+    the CPU backend jnp.asarray shares the memory of a NumPy array that is
+    64-byte aligned, so the test gives the cache such an array."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serving.kv_cache import SlotCache
+    kv = SlotCache(build_model(get_config("tiny_lm")), 2, 16)
+    buf = np.zeros(64 + kv.pos.nbytes, np.uint8)
+    off = -buf.ctypes.data % 64
+    kv.pos = buf[off:off + kv.pos.nbytes].view(np.int32)
+    kv.pos[:] = [3, 5]
+    kv.active[0] = True
+    pos = kv.positions()
+    kv.advance()
+    assert np.asarray(pos).tolist() == [3, 5]
+    assert kv.pos.tolist() == [4, 5]
