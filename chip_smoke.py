@@ -144,10 +144,13 @@ def serve(cfg, *, workers: int, concurrency: int, gen_tokens: int,
 
 # ----------------------------------------------------------------- parity
 def parity(inst: Instance, *, prompt_len: int, steps: int, seed: int) -> dict:
-    """Prefill one prompt and decode ``steps`` greedy tokens with the
-    instance's own compiled programs and params, then compare every logit
-    vector with ``LM.forward_seq`` over the whole sequence in float32."""
+    """Prefill one prompt with the instance's own program and params and
+    decode ``steps`` greedy tokens with ``LM.decode_step``, the step the
+    served decode program takes the argmax of, jitted alone for its logits;
+    then compare every logit vector with ``LM.forward_seq`` over the whole
+    sequence in float32."""
     model, params = inst.model, inst.params
+    decode = jax.jit(model.decode_step)
     rng = np.random.default_rng(seed + 1)
     seq = [int(t) for t in rng.integers(2, model.cfg.vocab_size, prompt_len)]
     logits, pcache = inst._prefill(params, {"tokens": jnp.asarray([seq])})
@@ -158,7 +161,7 @@ def parity(inst: Instance, *, prompt_len: int, steps: int, seed: int) -> dict:
         seq.append(int(np.argmax(got[-1])))
         tok = np.zeros(inst.slots, np.int32)
         tok[0] = seq[-1]
-        logits, kv.cache = inst._decode(
+        logits, kv.cache = decode(
             params, kv.cache, {"token": jnp.asarray(tok),
                                "pos": kv.positions()})
         kv.advance()

@@ -56,8 +56,29 @@ def image_params(model, arch: str):
 
 
 def image_programs(model):
-    """The jitted (prefill, decode) programs every instance of ``model`` runs."""
-    return jax.jit(model.prefill), jax.jit(model.decode_step)
+    """The jitted (prefill, decode) programs every instance of ``model`` runs.
+
+    The decode program is ``model.decode_step`` with greedy feedback kept on
+    the device: ``decode(params, (cache, fed), active)`` with ``fed`` =
+    ``{token, pos}`` per slot returns ``(token, (cache, fed))``, each slot's
+    greedy token as ``int32[slots]`` and what the next call takes: the new
+    cache, those tokens, and ``pos + active``. Inactive slots decode garbage
+    that nobody reads, and their positions stay."""
+    def decode_step(params, state, active):
+        cache, fed = state
+        logits, cache = model.decode_step(params, cache, fed)
+        token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return token, (cache, {"token": token, "pos": fed["pos"] + active})
+
+    return jax.jit(model.prefill), jax.jit(decode_step)
+
+
+def upload_feedback(last_tok, pos, active) -> tuple:
+    """The device's copies of a decode call's inputs, ``(fed, active)``, from
+    the host's mirrors. Copies: on the CPU backend a device array may share
+    the memory of the NumPy array it was made from."""
+    return jax.device_put(({"token": last_tok.copy(), "pos": pos.copy()},
+                           active.copy()))
 
 
 class Instance:
@@ -78,10 +99,9 @@ class Instance:
             kv0 = SlotCache(model, slots, max_len)
             warm = {"tokens": jnp.zeros((1, 16), jnp.int32)}
             jax.block_until_ready(prefill(params, warm)[0])
-            jax.block_until_ready(decode(
-                params, kv0.cache,
-                {"token": jnp.zeros(slots, jnp.int32),
-                 "pos": jnp.zeros(slots, jnp.int32)})[0])
+            fed, active = upload_feedback(np.zeros(slots, np.int32), kv0.pos,
+                                          kv0.active)
+            jax.block_until_ready(decode(params, (kv0.cache, fed), active)[0])
             _IMAGE_CACHE[key] = (model, params, prefill, decode)
         self.model, self.params, self._prefill, self._decode = _IMAGE_CACHE[key]
         self.kv = SlotCache(self.model, slots, max_len)
@@ -89,6 +109,11 @@ class Instance:
         self.last_used = time.monotonic()
         self.sampler = Random(rng_seed)
         self._last_tok = np.zeros(slots, np.int32)   # greedy-decode feedback
+        # the device's copies of _last_tok, kv.pos and kv.active: the last
+        # decode call's outputs, or an upload after an admission or release
+        self._fed = self._active = None
+        self.decode_calls = 0
+        self.feedback_uploads = 0
         self._slot_meta: Dict[int, object] = {}
         self.generated: Dict[int, list] = {}         # rid -> token ids
 
@@ -210,16 +235,21 @@ class Worker:
         inst._slot_meta[slot] = p
 
     def _decode_instance(self, inst: Instance):
-        """One decode step over every slot of ``inst``, feeding each active
-        slot's greedy token back for the next."""
+        """One decode step over every slot of ``inst``: one program, which
+        feeds each slot's greedy token back on the device, and one pull of
+        those tokens. The host's mirrors go up first only where an admission
+        or a release changed them since the last call."""
         with TraceAnnotation("engine.decode"):
-            tok = jnp.asarray(inst._last_tok)
-            logits, inst.kv.cache = inst._decode(
-                inst.params, inst.kv.cache,
-                {"token": tok, "pos": inst.kv.positions()})
-            jax.block_until_ready(logits)
+            inst.decode_calls += 1
+            if inst.kv.changed:
+                inst._fed, inst._active = upload_feedback(
+                    inst._last_tok, inst.kv.pos, inst.kv.active)
+                inst.kv.changed = False
+                inst.feedback_uploads += 1
+            tok, (inst.kv.cache, inst._fed) = inst._decode(
+                inst.params, (inst.kv.cache, inst._fed), inst._active)
             with TraceAnnotation("engine.sample"):
-                nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+                nxt = np.asarray(tok)
                 for s in range(inst.slots):
                     if inst.kv.active[s]:
                         inst._last_tok[s] = nxt[s]

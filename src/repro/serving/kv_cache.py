@@ -24,6 +24,9 @@ class SlotCache:
         self.active = np.zeros(slots, bool)
         self.rid = np.full(slots, -1, np.int64)
         self.remaining = np.zeros(slots, np.int32)
+        # set by admit and release: the device's copies of pos and active
+        # are stale until the engine uploads them again
+        self.changed = True
 
     def free_slots(self):
         return [i for i in range(self.slots) if not self.active[i]]
@@ -49,10 +52,12 @@ class SlotCache:
             self.active[slot] = True
             self.rid[slot] = rid
             self.remaining[slot] = decode_steps
+            self.changed = True
 
     def release(self, slot: int):
         self.active[slot] = False
         self.rid[slot] = -1
+        self.changed = True
 
     def positions(self) -> jnp.ndarray:
         """The next position of every slot, as a copy: an :meth:`advance`

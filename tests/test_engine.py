@@ -9,7 +9,7 @@ import pytest
 from repro.core.config_store import ConfigStore, ImageRegistry
 from repro.core.router import build_tree
 from repro.core.types import FunctionConfig, Request
-from repro.serving.engine import Engine, Worker
+from repro.serving.engine import Engine, Worker, image_programs
 
 
 @pytest.fixture(scope="module")
@@ -129,6 +129,107 @@ def test_request_generates_gen_tokens(platform, gen):
                  for rid, toks in inst.generated.items()}
     assert [len(generated[r.rid]) for r in reqs] == [gen] * len(reqs)
     assert all(inst.busy() == 0 for inst in w.instances[fn])
+
+
+class _HostFedWorker(Worker):
+    """The decode loop with feedback on the host: every call uploads the
+    tokens and positions, runs ``LM.decode_step`` alone, and pulls the
+    argmax of its logits."""
+
+    def _decode_instance(self, inst):
+        import jax
+        import jax.numpy as jnp
+        if not hasattr(self, "plain"):
+            self.plain = jax.jit(inst.model.decode_step)
+        logits, inst.kv.cache = self.plain(
+            inst.params, inst.kv.cache,
+            {"token": jnp.asarray(inst._last_tok.copy()),
+             "pos": inst.kv.positions()})
+        nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+        for s in range(inst.slots):
+            if inst.kv.active[s]:
+                inst._last_tok[s] = nxt[s]
+                rid = int(inst.kv.rid[s])
+                if rid in inst.generated:
+                    inst.generated[rid].append(int(nxt[s]))
+        inst.kv.advance()
+
+
+def _served(worker, arrivals, steps):
+    """Submit ``arrivals[k]`` (prompts) before step ``k`` of ``worker``, then
+    drain; every request's tokens, in submission order."""
+    rids = []
+    for k in range(steps):
+        for prompt in arrivals.get(k, []):
+            req = Request(fn="feed", arrival_t=0.0, size=len(prompt),
+                          payload=prompt)
+            rids.append(req.rid)
+            worker.submit(req)
+        worker.step()
+    worker.drain()
+    generated = {rid: toks for inst in worker.instances["feed"]
+                 for rid, toks in inst.generated.items()}
+    return [generated[rid] for rid in rids]
+
+
+@pytest.mark.slow
+def test_device_feedback_matches_host_feedback(platform):
+    """Staggered arrivals in three prompt buckets, with slots released and
+    refilled while others decode: every request's tokens equal, token for
+    token, those of the host-fed loop at the same slot count."""
+    store, registry = platform
+    store.put(FunctionConfig(name="feed", arch="tiny_lm", concurrency=4,
+                             gen_tokens=7, idle_timeout_s=60.0))
+    rng = np.random.default_rng(3)
+
+    def prompt(n):
+        return rng.integers(2, 4096, n).astype(np.int32)
+
+    arrivals = {0: [prompt(8), prompt(20)], 1: [prompt(40)],
+                3: [prompt(5), prompt(17), prompt(33)], 4: [prompt(12)],
+                9: [prompt(30), prompt(9)]}
+    got = _served(Worker("dev", store, registry, max_len=128), arrivals, 12)
+    want = _served(_HostFedWorker("host", store, registry, max_len=128),
+                   arrivals, 12)
+    assert [len(t) for t in got] == [7] * 9
+    assert got == want
+
+
+@pytest.mark.slow
+def test_feedback_uploads_follow_admissions_and_releases(platform):
+    """A decode call uploads the host's mirrors only after an admission or
+    a release; steady decode steps upload nothing."""
+    store, registry = platform
+    store.put(FunctionConfig(name="feed", arch="tiny_lm", concurrency=4,
+                             gen_tokens=6, idle_timeout_s=60.0))
+    w = Worker("count", store, registry, max_len=64)
+    w.submit(Request(fn="feed", arrival_t=0.0, size=8))
+    w.step()                                 # admission, then a decode call
+    inst = w.instances["feed"][0]
+    assert (inst.decode_calls, inst.feedback_uploads) == (1, 1)
+    for _ in range(2):
+        w.step()
+    assert (inst.decode_calls, inst.feedback_uploads) == (3, 1)
+    w.submit(Request(fn="feed", arrival_t=0.0, size=8))
+    w.step()                                 # a second admission
+    assert (inst.decode_calls, inst.feedback_uploads) == (4, 2)
+    w.step()                                 # the first request ends here
+    assert (inst.decode_calls, inst.feedback_uploads) == (5, 2)
+    w.step()                                 # after its release
+    assert (inst.decode_calls, inst.feedback_uploads) == (6, 3)
+    w.drain()
+    assert (inst.decode_calls, inst.feedback_uploads) == (8, 3)
+
+
+def test_decode_program_keeps_its_name():
+    """The fused decode program is still called ``decode_step``, so XLA
+    names it ``jit_decode_step``, the name the benchmark's trace readers
+    count decode calls by."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    prefill, decode = image_programs(build_model(get_config("tiny_lm")))
+    assert decode.__name__ == "decode_step"
+    assert prefill.__name__ == "prefill"
 
 
 _WEIGHT_DIGEST = """

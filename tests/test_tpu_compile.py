@@ -85,9 +85,10 @@ def _compile(model, sh, program: str, *, seq=2048, slots=4, max_len=4096):
         return prefill.lower(params, {"tokens": _spec(sh, (1, seq), jnp.int32)}
                              ).compile()
     cache, _ = model.cache_specs(slots, max_len)
-    step = {"token": _spec(sh, (slots,), jnp.int32),
-            "pos": _spec(sh, (slots,), jnp.int32)}
-    return decode.lower(params, _on(sh, cache), step).compile()
+    fed = {"token": _spec(sh, (slots,), jnp.int32),
+           "pos": _spec(sh, (slots,), jnp.int32)}
+    return decode.lower(params, (_on(sh, cache), fed),
+                        _spec(sh, (slots,), jnp.bool_)).compile()
 
 
 def _kernel_case(name, sh):
