@@ -1,62 +1,40 @@
-"""Operations and bytes of the served programs of a dense GQA decoder.
+"""Operations and bytes of the served programs, counted by the model's family.
 
 Counted from the configuration's shapes, as the algorithm needs them: a
 multiply-add is two operations, causal attention counts only the keys a
 query sees, and bytes are those a step has to move at the least (each weight
-read once, the keys and values of valid positions read once, the new ones
-and the logits written once). Padding, masked positions and copies that the
-program makes beyond that are waste and are not counted, so a share of the
-roofline built on these counts cannot pass 100%.
+read once, the state of valid positions read once, the new state and the
+logits written once). Padding, masked positions and copies that the program
+makes beyond that are waste and are not counted, so a share of the roofline
+built on these counts cannot pass 100%. Each family counts in its own file
+(``families/<reference>.py``); these functions hand the configuration to it.
 """
 from __future__ import annotations
 
-BYTES = 2                       # bfloat16 weights, caches and logits
-
-
-def _dims(cfg: dict):
-    D, F, V = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
-    q = cfg["num_attention_heads"] * cfg["head_dim"]
-    kv = cfg["num_key_value_heads"] * cfg["head_dim"]
-    return cfg["num_hidden_layers"], D, F, V, q, kv
+from bench.families import family
 
 
 def layer_params(cfg: dict) -> int:
-    L, D, F, V, q, kv = _dims(cfg)
-    return D * q + 2 * D * kv + q * D + 3 * D * F + 2 * D
+    return family(cfg).layer_params(cfg)
 
 
 def params(cfg: dict) -> int:
-    L, D, F, V, q, kv = _dims(cfg)
-    return L * layer_params(cfg) + 2 * V * D + D
-
-
-def _matmul_flops_per_token(cfg: dict) -> int:
-    L, D, F, V, q, kv = _dims(cfg)
-    return L * 2 * (D * q + 2 * D * kv + q * D + 3 * D * F)
+    return family(cfg).params(cfg)
 
 
 def prefill_flops(cfg: dict, n: int) -> int:
     """One prompt of ``n`` tokens, logits of its last token only."""
-    L, D, F, V, q, kv = _dims(cfg)
-    attn = L * 2 * q * n * (n + 1)          # QK^T and PV over the causal triangle
-    return n * _matmul_flops_per_token(cfg) + attn + 2 * D * V
+    return family(cfg).prefill_flops(cfg, n)
 
 
 def decode_flops(cfg: dict, seen: list) -> int:
-    """One decode step; ``seen[s]`` is the number of keys active slot ``s``
-    attends, its new token included."""
-    L, D, F, V, q, kv = _dims(cfg)
-    per_token = _matmul_flops_per_token(cfg) + 2 * D * V
-    return sum(per_token + L * 4 * q * n for n in seen)
+    """One decode step; ``seen[s]`` is the number of positions active slot
+    ``s`` has seen, its new token included."""
+    return family(cfg).decode_flops(cfg, seen)
 
 
 def decode_bytes(cfg: dict, seen: list) -> int:
-    """One decode step over the active slots: every weight once, the
-    embedding rows of the batch, the cached keys and values of valid
-    positions read once, the new ones and the logits written once."""
-    L, D, F, V, q, kv = _dims(cfg)
-    weights = L * layer_params(cfg) + V * D + D
-    b = len(seen)
-    kv_read = sum(L * 2 * kv * (n - 1) for n in seen)
-    kv_write = b * L * 2 * kv
-    return BYTES * (weights + b * D + kv_read + kv_write + b * V)
+    """One decode step over the active slots: every weight once, the batch's
+    embedding rows, the state of valid positions read once, the new state
+    and the logits written once."""
+    return family(cfg).decode_bytes(cfg, seen)

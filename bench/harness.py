@@ -18,6 +18,7 @@ import jax
 import numpy as np
 
 from bench import traffic, weights
+from bench.families import family
 
 FN = "bench"
 DRAIN_S = 60.0          # longest wait after the window for due requests
@@ -48,18 +49,15 @@ class Step:
 
 
 def model_config(cfg: dict):
-    """The engine's ModelConfig for a configuration file, registered."""
+    """The engine's ModelConfig for a configuration file, registered: the
+    fields every family shares here, the rest from the family's file."""
     from repro.configs import get_config, register
-    base = get_config(cfg["arch"])
+    fields = family(cfg).engine_fields(cfg)
     mc = dataclasses.replace(
-        base, name=f"bench_{cfg['name']}",
+        get_config(cfg["arch"]), name=f"bench_{cfg['name']}",
         num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        norm_eps=cfg["rms_norm_eps"], rope_theta=cfg["rope_theta"],
-        tie_embeddings=cfg["tie_word_embeddings"], dtype=cfg["torch_dtype"],
-        gated_mlp=True, qk_norm=False, moe=None, mamba=None, sliding_window=0)
+        vocab_size=cfg["vocab_size"], tie_embeddings=cfg["tie_word_embeddings"],
+        dtype=cfg["torch_dtype"], **fields)
     return register(mc)
 
 
@@ -69,11 +67,12 @@ def _name(path) -> str:
 
 def seeded_params(cfg: dict, seed: int):
     """``image_params`` for the engine: the weights of ``seed`` in the
-    engine's tree, made on the device by one jitted program."""
+    engine's tree, made on the device by one jitted program. Each weight's
+    name, shape and dtype must be the family's."""
     def make(model, arch):
         flat, treedef = jax.tree_util.tree_flatten_with_path(model.abstract_params())
         got = {_name(p): (tuple(a.shape), str(a.dtype)) for p, a in flat}
-        want = {n: (tuple(s), "bfloat16") for n, s in weights.layout(cfg).items()}
+        want = weights.layout(cfg)
         if got != want:
             raise RuntimeError(f"engine parameter tree {got} is not {want}")
         names = [_name(p) for p, _ in flat]

@@ -1,18 +1,17 @@
-"""Plain float32 reference of a dense GQA decoder, and its low-precision control.
+"""Plain float32 reference of a served model, layer by layer, and its low-precision controls.
 
-The Llama form that DeepSeek-Coder and Mistral-Large publish: RMSNorm before
-attention and before a SwiGLU MLP, rotary embedding by halves, grouped-query
-causal attention, an untied output head. It imports nothing of the engine
-and takes nothing the engine made: it draws its weights from the seed with
-:mod:`bench.weights`, one layer at a time, and runs every sequence alone, in
-float32 with matmuls at ``HIGHEST`` precision. RMSNorm gains are applied as
-``1 + w``, the engine's storage of a gain (``weights.py``).
+The model's layers and head are its family's (``families/<reference>.py``);
+this file runs them. It imports nothing of the engine and takes nothing the
+engine made: it draws its weights from the seed with :mod:`bench.weights`,
+one layer at a time, and runs every sequence alone, in float32 with matmuls
+at ``HIGHEST`` precision.
 
 The controls put the next precision below the configuration's bfloat16 in
 the engine's place: with ``quant="fp8"`` (the control that sets the limits)
-every matmul of the forward pass takes float8 e4m3 weights (a scale per
-output column) and activations (a scale per row); ``quant="int8"`` does the
-same in int8, which reads too close to sound runs to bound them (PERF.md).
+every matmul of the forward pass (each goes through :func:`dot`) takes
+float8 e4m3 weights (a scale per output column) and activations (a scale per
+row); ``quant="int8"`` does the same in int8, which reads too close to sound
+runs to bound them (PERF.md).
 """
 from __future__ import annotations
 
@@ -23,10 +22,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import weights
+from bench.families import family
 
 HIGHEST = jax.lax.Precision.HIGHEST
 PAD = 512              # sequences run padded to a multiple of this
-Q_CHUNK = 256          # query rows per attention block
 
 
 def _quantize(a, axis):
@@ -35,86 +34,47 @@ def _quantize(a, axis):
     return jnp.round(a / scale), scale
 
 
-def _dot(x, w, quant):
+def dot(x, w, quant):
     """x [T, i] @ w [i, o] in float32."""
     w = w.astype(jnp.float32)
     if quant == "int8":
-        xq, sx = _quantize(x, -1)
-        wq, sw = _quantize(w, 0)
-        return jnp.dot(xq, wq, precision=HIGHEST) * sx * sw
+        qx, sx = _quantize(x, -1)
+        qw, sw = _quantize(w, 0)
+        return jnp.dot(qx, qw, precision=HIGHEST) * sx * sw
     if quant == "fp8":
         f8 = jnp.float8_e4m3fn
         sx = jnp.max(jnp.abs(x), -1, keepdims=True) / 448.0
         sw = jnp.max(jnp.abs(w), 0, keepdims=True) / 448.0
-        xq = (x / sx).astype(f8).astype(jnp.float32)
-        wq = (w / sw).astype(f8).astype(jnp.float32)
-        return jnp.dot(xq, wq, precision=HIGHEST) * sx * sw
+        qx = (x / sx).astype(f8).astype(jnp.float32)
+        qw = (w / sw).astype(f8).astype(jnp.float32)
+        return jnp.dot(qx, qw, precision=HIGHEST) * sx * sw
     return jnp.dot(x, w, precision=HIGHEST)
 
 
-def _rms_norm(x, g, eps):
+def rms_norm(x, g, eps):
+    """RMSNorm with the gain stored as ``g`` and applied as ``1 + g``, as the
+    engine stores a gain."""
     x = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
     return x * (1.0 + g.astype(jnp.float32))
 
 
-def _rope(x, theta):
-    """x [T, heads, hd] at positions 0..T-1, rotated by halves."""
-    T, _, hd = x.shape
-    half = hd // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * freq
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _attention(q, k, v):
-    """Causal grouped-query attention. q [T, H, hd]; k, v [T, KV, hd]."""
-    T, H, hd = q.shape
-    G = H // k.shape[1]
-    k = jnp.repeat(k, G, axis=1)
-    v = jnp.repeat(v, G, axis=1)
-    keys = jnp.arange(T)
-
-    def block(i):
-        qi = jax.lax.dynamic_slice_in_dim(q, i * Q_CHUNK, Q_CHUNK)
-        s = jnp.einsum("qhd,khd->hqk", qi, k, precision=HIGHEST) * hd ** -0.5
-        rows = i * Q_CHUNK + jnp.arange(Q_CHUNK)
-        s = jnp.where(keys[None, None, :] <= rows[None, :, None], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("hqk,khd->qhd", p, v, precision=HIGHEST)
-
-    out = jax.lax.map(block, jnp.arange(T // Q_CHUNK))
-    return out.reshape(T, H, hd)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "quant"))
-def _layer(x, w, *, cfg, quant):
-    c = dict(cfg)
-    T = x.shape[0]
-    H, KV, hd = c["num_attention_heads"], c["num_key_value_heads"], c["head_dim"]
-    eps, theta = c["rms_norm_eps"], c["rope_theta"]
-    h = _rms_norm(x, w["norm1"], eps)
-    q = _rope(_dot(h, w["wq"], quant).reshape(T, H, hd), theta)
-    k = _rope(_dot(h, w["wk"], quant).reshape(T, KV, hd), theta)
-    v = _dot(h, w["wv"], quant).reshape(T, KV, hd)
-    x = x + _dot(_attention(q, k, v).reshape(T, H * hd), w["wo"], quant)
-    h = _rms_norm(x, w["norm2"], eps)
-    gate = jax.nn.silu(_dot(h, w["wg"], quant)) * _dot(h, w["wi"], quant)
-    return x + _dot(gate, w["wo_mlp"], quant)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "quant"))
-def _head(x, top, *, cfg, quant):
-    h = _rms_norm(x, top["final_norm"], dict(cfg)["rms_norm_eps"])
-    return _dot(h, top["unembed"].T, quant)
-
-
 def _frozen(cfg: dict):
-    keep = ("num_hidden_layers", "hidden_size", "intermediate_size", "vocab_size",
-            "num_attention_heads", "num_key_value_heads", "head_dim",
-            "rms_norm_eps", "rope_theta")
-    return tuple((k, cfg[k]) for k in keep)
+    """The configuration's scalar keys, hashable: what a family may read."""
+    return tuple((k, v) for k, v in cfg.items()
+                 if v is None or isinstance(v, (bool, int, float, str)))
+
+
+@functools.lru_cache
+def _programs(fc, quant: str):
+    """The family's layer and head, jitted, for one configuration and control."""
+    cfg, fam = dict(fc), family(dict(fc))
+
+    def layer(x, w):
+        return fam.layer(x, w, cfg, quant)
+
+    def head(x, top):
+        return fam.head(x, top, cfg, quant)
+    return jax.jit(layer), jax.jit(head)
 
 
 @functools.lru_cache
@@ -130,6 +90,7 @@ def logits(cfg: dict, seed: int, seqs: list, rows: list, quant: str = "none"):
     fc = _frozen(cfg)
     key = weights.base_key(seed)
     draw_top, draw_layer = _drawers(fc)
+    run_layer, run_head = _programs(fc, quant)
     top = draw_top(key)
     xs = []
     for s in seqs:
@@ -139,9 +100,9 @@ def logits(cfg: dict, seed: int, seqs: list, rows: list, quant: str = "none"):
         xs.append(top["embed"][jnp.asarray(toks)].astype(jnp.float32))
     for layer in range(cfg["num_hidden_layers"]):
         w = draw_layer(key, layer)
-        xs = [_layer(x, w, cfg=fc, quant=quant) for x in xs]
+        xs = [run_layer(x, w) for x in xs]
         del w
-    return [np.asarray(_head(x[jnp.asarray(r)], top, cfg=fc, quant=quant))
+    return [np.asarray(run_head(x[jnp.asarray(r)], top))
             for x, r in zip(xs, rows)]
 
 

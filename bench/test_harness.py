@@ -22,8 +22,12 @@ BENCH = {
     "per_layer": [{"name": "compile_s", "unit": "s", "moves": "setup_s"}]}
 
 
-def tiny_run(loop, seed=7, trace=False):
-    cfg = json.loads((HERE / "testdata" / "tiny.json").read_text())
+def tiny_cfg():
+    return json.loads((HERE / "testdata" / "tiny.json").read_text())
+
+
+def tiny_run(loop, seed=7, trace=False, cfg=None):
+    cfg = cfg or tiny_cfg()
     mix = traffic.load(f"tiny_{loop}", HERE / "testdata")
     return run.run_cell({"name": f"tiny.{loop}", "chips": 1}, cfg, mix, seed=seed,
                         seconds=1.5, trace=trace, bench=BENCH, limits=TINY_LIMITS,

@@ -1,7 +1,7 @@
 """Every name in BENCHMARK.json leads to its file."""
 import json
 
-from bench import check, run, traffic
+from bench import check, families, run, traffic
 
 
 def test_every_cell_and_metric_has_its_files():
@@ -23,3 +23,11 @@ def test_config_files_hold_the_sizes_they_claim():
         cfg = json.loads((run.ROOT / c["file"]).read_text())
         assert cfg["source"] == c["source"]
         assert sorted(cfg["reduced"]) == sorted(c["reduced"])
+
+
+def test_every_config_names_a_family_file_that_keeps_the_contract():
+    bench = run.spec()
+    for c in bench["configs"]:
+        cfg = json.loads((run.ROOT / c["file"]).read_text())
+        fam = families.family(cfg)
+        assert all(callable(getattr(fam, f)) for f in families.REQUIRED)

@@ -1,4 +1,4 @@
-"""Seeded weights of a dense GQA decoder, drawn on the device.
+"""Seeded weights of a served model, drawn on the device.
 
 The benchmark makes an image's weights from ``--seed`` and hands them to the
 engine; the plain reference draws the same values again, layer by layer, from
@@ -6,11 +6,10 @@ the same seed. Each weight has a name; every layer of it is drawn from a key
 folded from the seed, the name and the layer index, so one layer can be
 redrawn alone.
 
-The names and shapes are the engine's parameter tree (``LM.param_specs`` for
-a dense model: layers stacked on a leading axis under ``slots.0``); the
-harness checks the engine's tree against :func:`layout` before a run.
-RMSNorm gains are stored as ``w`` and applied as ``1 + w``, as the engine
-does.
+The names, shapes and dtypes come from the configuration's family
+(``families/<reference>.py``): the engine's parameter tree, layer weights
+stacked on a leading axis under ``slots.0``. The harness checks the engine's
+tree against :func:`layout` before a run.
 """
 from __future__ import annotations
 
@@ -19,21 +18,18 @@ import zlib
 import jax
 import jax.numpy as jnp
 
-DTYPE = jnp.bfloat16
+from bench.families import family
+
+DTYPE = "bfloat16"     # of every weight that the family's DTYPES leaves out
 
 
 def layout(cfg: dict) -> dict:
-    """Name -> shape of every weight; layer weights carry a leading layer axis."""
-    L, D, V = cfg["num_hidden_layers"], cfg["hidden_size"], cfg["vocab_size"]
-    q = cfg["num_attention_heads"] * cfg["head_dim"]
-    kv = cfg["num_key_value_heads"] * cfg["head_dim"]
-    F = cfg["intermediate_size"]
-    return {
-        "embed": (V, D), "unembed": (V, D), "final_norm": (D,),
-        "slots.0.norm1": (L, D), "slots.0.wq": (L, D, q), "slots.0.wk": (L, D, kv),
-        "slots.0.wv": (L, D, kv), "slots.0.wo": (L, q, D), "slots.0.norm2": (L, D),
-        "slots.0.wi": (L, D, F), "slots.0.wg": (L, D, F), "slots.0.wo_mlp": (L, F, D),
-    }
+    """Name -> (shape, dtype) of every weight; layer weights carry a leading
+    layer axis."""
+    fam = family(cfg)
+    dtypes = getattr(fam, "DTYPES", {})
+    return {n: (tuple(s), str(jnp.dtype(dtypes.get(n, DTYPE))))
+            for n, s in fam.layout(cfg).items()}
 
 
 def base_key(seed: int) -> jax.Array:
@@ -51,12 +47,18 @@ def _std(name: str, shape) -> float:
     return shape[-2] ** -0.5                 # matrices: 1/sqrt(fan_in)
 
 
-def draw(key, name: str, shape) -> jax.Array:
-    """One weight (one layer of it, for layer weights) from its key."""
+def draw(key, name: str, shape, dtype) -> jax.Array:
+    """One weight (one layer of it, for layer weights) from its key: norm
+    gains 0.1 N(0, 1), the embedding N(0, 1), every other weight normal by
+    its fan-in. A family with other rules defines its own ``draw``."""
     z = jax.random.normal(key, shape, jnp.float32)
     if "norm" in name:
-        return (0.1 * z).astype(DTYPE)
-    return (_std(name, shape) * z).astype(DTYPE)
+        return (0.1 * z).astype(dtype)
+    return (_std(name, shape) * z).astype(dtype)
+
+
+def _drawer(cfg: dict):
+    return getattr(family(cfg), "draw", draw)
 
 
 def name_key(key, name: str):
@@ -69,28 +71,30 @@ def _layer_key(key, name: str, layer):
 
 def layer_weights(key, cfg: dict, layer) -> dict:
     """Every layer weight of layer ``layer`` (traceable)."""
-    return {name.split(".")[-1]: draw(_layer_key(key, name, layer), name, shape[1:])
-            for name, shape in layout(cfg).items() if name.startswith("slots.")}
+    draw_ = _drawer(cfg)
+    return {name.split(".")[-1]: draw_(_layer_key(key, name, layer), name, shape[1:], dt)
+            for name, (shape, dt) in layout(cfg).items() if name.startswith("slots.")}
 
 
 def all_weights(key, cfg: dict) -> dict:
     """Every weight, flat by name, layer weights stacked (traceable). Each
     layer is drawn into its place in the stack, so no weight is ever held
     twice."""
-    out = {}
-    for name, shape in layout(cfg).items():
+    draw_, out = _drawer(cfg), {}
+    for name, (shape, dt) in layout(cfg).items():
         if not name.startswith("slots."):
-            out[name] = draw(name_key(key, name), name, shape)
+            out[name] = draw_(name_key(key, name), name, shape, dt)
             continue
-        w = jnp.zeros(shape, DTYPE)
+        w = jnp.zeros(shape, dt)
         for layer in range(shape[0]):
-            w = w.at[layer].set(draw(_layer_key(key, name, layer), name, shape[1:]))
+            w = w.at[layer].set(draw_(_layer_key(key, name, layer), name, shape[1:], dt))
         out[name] = w
     return out
 
 
 def top_weights(key, cfg: dict) -> dict:
     """The weights outside the layers (traceable)."""
-    return {name: draw(name_key(key, name), name, shape)
-            for name, shape in layout(cfg).items()
+    draw_ = _drawer(cfg)
+    return {name: draw_(name_key(key, name), name, shape, dt)
+            for name, (shape, dt) in layout(cfg).items()
             if not name.startswith("slots.")}
