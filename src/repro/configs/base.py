@@ -38,6 +38,13 @@ class MambaConfig:
     d_state: int = 16
     d_conv: int = 4
     dt_rank: int = 0                   # 0 => ceil(d_model / 16)
+    # eps of the weightless RMS normalisation of dt, B and C after x_proj
+    # (Falcon-Mamba); 0 => none (Mamba-1 as published, jamba)
+    mixer_rms_eps: float = 0.0
+    # carry the model's residual stream between layers in float32 (as the
+    # Mamba family publishes it); each layer still takes its normed input,
+    # and the head its input, in the model dtype
+    residual_in_fp32: bool = False
 
 
 @dataclass(frozen=True)
@@ -97,6 +104,16 @@ class ModelConfig:
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
 
+    @property
+    def residual_in_fp32(self) -> bool:
+        """Whether the residual stream is carried in float32 (``MambaConfig``)."""
+        return self.mamba is not None and self.mamba.residual_in_fp32
+
+    @property
+    def recurrent(self) -> bool:
+        """Whether some layer carries recurrent state (a Mamba mixer)."""
+        return any(self.block_kind(i) == BLOCK_MAMBA for i in range(self.num_layers))
+
     def block_kind(self, layer_idx: int) -> str:
         """attn or mamba for layer `layer_idx` (jamba interleave)."""
         if self.attention_free:
@@ -140,7 +157,7 @@ class ModelConfig:
             else:
                 m = c.mamba
                 n += d * 2 * m.d_inner             # in_proj (x and z)
-                n += m.d_conv * m.d_inner          # conv1d
+                n += m.d_conv * m.d_inner + m.d_inner   # conv1d + bias
                 n += m.d_inner * (m.dt_rank + 2 * m.d_state)   # x_proj
                 n += m.dt_rank * m.d_inner + m.d_inner         # dt_proj + bias
                 n += m.d_inner * m.d_state + m.d_inner         # A_log, D
@@ -153,7 +170,8 @@ class ModelConfig:
                 n += e.num_experts * 3 * d * e.expert_ff
             elif c.d_ff > 0:
                 n += (3 if c.gated_mlp else 2) * d * c.d_ff
-            n += d                                 # pre-mlp norm
+            if c.is_moe_layer(i) or c.d_ff > 0:
+                n += d                             # pre-mlp norm
         n += d                                     # final norm
         return n
 
@@ -291,8 +309,8 @@ def reduced(cfg: ModelConfig, *, layers: int = 2, d_model: int = 64,
                       top_k=min(2, cfg.moe.top_k), expert_ff=d_model * 2)
     mamba = None
     if cfg.mamba is not None:
-        mamba = MambaConfig(d_inner=2 * d_model, d_state=8, d_conv=4,
-                            dt_rank=max(4, d_model // 16))
+        mamba = replace(cfg.mamba, d_inner=2 * d_model, d_state=8, d_conv=4,
+                        dt_rank=max(4, d_model // 16))
     return replace(
         cfg,
         num_layers=layers, d_model=d_model, num_heads=heads, num_kv_heads=kv,

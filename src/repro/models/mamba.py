@@ -10,6 +10,12 @@ This mirrors the VMEM-chunked structure of the Pallas kernel in
 Decode mode is the O(1) recurrence: one state update per token; the "cache"
 is (conv ring window, SSM state) — constant in sequence length, which is why
 falcon-mamba/jamba run the long_500k cell.
+
+Falcon-Mamba normalises dt, B and C after ``x_proj`` by a weightless RMS
+norm (``MambaConfig.mixer_rms_eps``; 0 leaves it out, as Mamba-1 does).
+The scopes ``mamba.conv``, ``mamba.scan`` and ``mamba.step`` name the causal
+conv, the sequence scan and the one-token state update in the profiler's op
+view and in HLO dumps.
 """
 from __future__ import annotations
 
@@ -77,6 +83,25 @@ def _causal_conv(x: jax.Array, w: jax.Array, carry: jax.Array = None):
     return out, new_carry
 
 
+def _rms(v: jax.Array, eps: float) -> jax.Array:
+    """Weightless RMS normalisation over the last axis, in float32."""
+    v32 = v.astype(jnp.float32)
+    return (v32 * jax.lax.rsqrt(jnp.mean(v32 * v32, -1, keepdims=True) + eps)
+            ).astype(v.dtype)
+
+
+def _selection(xi: jax.Array, p: dict, m):
+    """dt [..., DI] (f32, after softplus), B and C [..., N] of the conv's
+    output xi [..., DI]."""
+    bcdt = jnp.einsum("...e,er->...r", xi, p["x_proj"])  # [..., dt_rank+2N]
+    dt, Bc, Cc = jnp.split(bcdt, [m.dt_rank, m.dt_rank + m.d_state], axis=-1)
+    if m.mixer_rms_eps:
+        dt, Bc, Cc = (_rms(v, m.mixer_rms_eps) for v in (dt, Bc, Cc))
+    dt = jax.nn.softplus(jnp.einsum("...r,re->...e", dt, p["dt_proj"])
+                         + p["dt_bias"]).astype(jnp.float32)
+    return dt, Bc, Cc
+
+
 def mamba_forward(x: jax.Array, p: dict, cfg, *, chunk: int = 256,
                   unroll: bool = False) -> Tuple[jax.Array, dict]:
     """Sequence mode. x: [B, S, D] -> (y [B, S, D], cache {conv, ssm})."""
@@ -85,19 +110,18 @@ def mamba_forward(x: jax.Array, p: dict, cfg, *, chunk: int = 256,
     xi = jnp.einsum("bsd,de->bse", x, p["in_x"])         # [B, S, DI]
     z = jnp.einsum("bsd,de->bse", x, p["in_z"])
     xi = shard_act(xi, ("act_batch", "act_seq", "act_mlp"))
-    xi, conv_carry = _causal_conv(xi, p["conv_w"])
+    with jax.named_scope("mamba.conv"):
+        xi, conv_carry = _causal_conv(xi, p["conv_w"])
     xi = jax.nn.silu(xi + p["conv_b"])
 
-    bcdt = jnp.einsum("bse,er->bsr", xi, p["x_proj"])    # [B,S,dt_rank+2N]
-    dt, Bc, Cc = jnp.split(bcdt, [m.dt_rank, m.dt_rank + m.d_state], axis=-1)
-    dt = jax.nn.softplus(jnp.einsum("bsr,re->bse", dt, p["dt_proj"])
-                         + p["dt_bias"]).astype(jnp.float32)          # [B,S,DI]
+    dt, Bc, Cc = _selection(xi, p, m)                    # dt [B, S, DI]
     A = -jnp.exp(p["A_log"].astype(jnp.float32))                      # [DI, N]
-    y, h_last = _ssm_chunk_scan(dt, xi.astype(jnp.float32),
-                                Bc.astype(jnp.float32),
-                                Cc.astype(jnp.float32), A,
-                                jnp.zeros((B, m.d_inner, m.d_state),
-                                          jnp.float32), chunk, unroll)
+    with jax.named_scope("mamba.scan"):
+        y, h_last = _ssm_chunk_scan(dt, xi.astype(jnp.float32),
+                                    Bc.astype(jnp.float32),
+                                    Cc.astype(jnp.float32), A,
+                                    jnp.zeros((B, m.d_inner, m.d_state),
+                                              jnp.float32), chunk, unroll)
     y = (y + xi.astype(jnp.float32) * p["D"].astype(jnp.float32)) \
         * jax.nn.silu(z.astype(jnp.float32))
     out = jnp.einsum("bse,ed->bsd", y.astype(x.dtype), p["out_proj"])
@@ -109,18 +133,18 @@ def mamba_decode(x: jax.Array, p: dict, cfg, cache: dict) -> Tuple[jax.Array, di
     m = cfg.mamba
     xi = jnp.einsum("bd,de->be", x, p["in_x"])
     z = jnp.einsum("bd,de->be", x, p["in_z"])
-    xi3, conv_carry = _causal_conv(xi[:, None], p["conv_w"], cache["conv"].astype(xi.dtype))
+    with jax.named_scope("mamba.conv"):
+        xi3, conv_carry = _causal_conv(xi[:, None], p["conv_w"],
+                                       cache["conv"].astype(xi.dtype))
     xi = jax.nn.silu(xi3[:, 0] + p["conv_b"])
 
-    bcdt = jnp.einsum("be,er->br", xi, p["x_proj"])
-    dt, Bc, Cc = jnp.split(bcdt, [m.dt_rank, m.dt_rank + m.d_state], axis=-1)
-    dt = jax.nn.softplus(jnp.einsum("br,re->be", dt, p["dt_proj"])
-                         + p["dt_bias"]).astype(jnp.float32)
+    dt, Bc, Cc = _selection(xi, p, m)
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
-    a = jnp.exp(dt[..., None] * A)                                    # [B,DI,N]
-    bx = dt[..., None] * Bc[:, None, :].astype(jnp.float32) * xi[..., None].astype(jnp.float32)
-    h = a * cache["ssm"] + bx
-    y = jnp.einsum("ben,bn->be", h, Cc.astype(jnp.float32))
+    with jax.named_scope("mamba.step"):
+        a = jnp.exp(dt[..., None] * A)                                # [B,DI,N]
+        bx = dt[..., None] * Bc[:, None, :].astype(jnp.float32) * xi[..., None].astype(jnp.float32)
+        h = a * cache["ssm"] + bx
+        y = jnp.einsum("ben,bn->be", h, Cc.astype(jnp.float32))
     y = (y + xi.astype(jnp.float32) * p["D"].astype(jnp.float32)) \
         * jax.nn.silu(z.astype(jnp.float32))
     out = jnp.einsum("be,ed->bd", y.astype(x.dtype), p["out_proj"])

@@ -127,7 +127,8 @@ class LM:
                                         ("w_layers", "w_dinner", "w_state"), init="mamba_a")
                 ps["D"] = ParamSpec((K, DI), ("w_layers", "w_dinner"), init="ones")
                 ps["out_proj"] = ParamSpec((K, DI, D), ("w_layers", "w_dinner", "w_embed"))
-            ps["norm2"] = ParamSpec((K, D), ("w_layers", None), init="zeros")
+            if sk.is_moe or c.d_ff > 0:
+                ps["norm2"] = ParamSpec((K, D), ("w_layers", None), init="zeros")
             if sk.is_moe:
                 e = c.moe
                 E, F = e.num_experts, e.expert_ff
@@ -178,12 +179,21 @@ class LM:
             else ("act_batch", "act_vocab")
         return shard_act(out, names)
 
+    def _stream(self, x) -> jax.Array:
+        """The residual stream as carried between layers: float32 where the
+        model publishes ``residual_in_fp32``, else as it is."""
+        return x.astype(jnp.float32) if self.cfg.residual_in_fp32 else x
+
+    def _compute(self, h) -> jax.Array:
+        """A normed stream in the model dtype, as layers and the head take it."""
+        return h.astype(self.cfg.dtype) if self.cfg.residual_in_fp32 else h
+
     # ------------------------------------------------------------------
     # Blocks
     # ------------------------------------------------------------------
     def _block_seq(self, x, p, sk: SlotKind, positions):
         c = self.cfg
-        h = rms_norm(x, p["norm1"], c.norm_eps)
+        h = self._compute(rms_norm(x, p["norm1"], c.norm_eps))
         if sk.kind == BLOCK_ATTN:
             h, cache = attn_mod.attn_forward(
                 h, p, c, sk.is_local, positions, theta=sk.theta,
@@ -193,12 +203,13 @@ class LM:
                                                unroll=self.unroll)
         x = x + h
         x = shard_act(x, ("act_batch", "act_seq", "act_embed"))
-        h = rms_norm(x, p["norm2"], c.norm_eps)
         if sk.is_moe:
+            h = self._compute(rms_norm(x, p["norm2"], c.norm_eps))
             x = x + moe_mod.moe_forward(h, {"router": p["router"], "wi": p["moe_wi"],
                                             "wg": p["moe_wg"], "wo": p["moe_wo"]}, c,
                                         unroll=self.unroll)
         elif c.d_ff > 0:
+            h = self._compute(rms_norm(x, p["norm2"], c.norm_eps))
             x = x + mlp(h, {"wi": p["wi"], "wg": p.get("wg"), "wo": p["wo_mlp"]},
                         c.gated_mlp)
         x = shard_act(x, ("act_batch", "act_seq", "act_embed"))
@@ -206,7 +217,7 @@ class LM:
 
     def _block_decode(self, x, p, sk: SlotKind, cache, positions):
         c = self.cfg
-        h = rms_norm(x, p["norm1"], c.norm_eps)
+        h = self._compute(rms_norm(x, p["norm1"], c.norm_eps))
         if sk.kind == BLOCK_ATTN:
             h, cache = attn_mod.attn_decode(h, p, c, sk.is_local, cache,
                                             positions, theta=sk.theta,
@@ -214,11 +225,12 @@ class LM:
         else:
             h, cache = mamba_mod.mamba_decode(h, p, c, cache)
         x = x + h
-        h = rms_norm(x, p["norm2"], c.norm_eps)
         if sk.is_moe:
+            h = self._compute(rms_norm(x, p["norm2"], c.norm_eps))
             x = x + moe_mod.moe_forward(h, {"router": p["router"], "wi": p["moe_wi"],
                                             "wg": p["moe_wg"], "wo": p["moe_wo"]}, c)
         elif c.d_ff > 0:
+            h = self._compute(rms_norm(x, p["norm2"], c.norm_eps))
             x = x + mlp(h[:, None], {"wi": p["wi"], "wg": p.get("wg"),
                                      "wo": p["wo_mlp"]}, c.gated_mlp)[:, 0]
         return x, cache
@@ -230,7 +242,7 @@ class LM:
                     remat: Optional[bool] = None):
         c = self.cfg
         use_remat = c.remat if remat is None else remat
-        x = self.embed_input(params, batch)
+        x = self._stream(self.embed_input(params, batch))
         B, S, _ = x.shape
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         x = shard_act(x, ("act_batch", "act_seq", "act_embed"))
@@ -260,10 +272,10 @@ class LM:
                 caches = jax.tree.map(lambda *xs: jnp.stack(xs), *all_caches)
             else:
                 caches = None
-            x = rms_norm(x, params["final_norm"], c.norm_eps)
+            x = self._compute(rms_norm(x, params["final_norm"], c.norm_eps))
             return x, caches
         x, caches = jax.lax.scan(body, x, params["slots"])
-        x = rms_norm(x, params["final_norm"], c.norm_eps)
+        x = self._compute(rms_norm(x, params["final_norm"], c.norm_eps))
         return x, caches if want_cache else None
 
     def loss_fn(self, params, batch):
@@ -319,7 +331,7 @@ class LM:
     def decode_step(self, params, cache, batch):
         """batch: {token: [B] int32, pos: [B] int32}. Returns (logits, cache)."""
         c = self.cfg
-        x = jnp.take(params["embed"], batch["token"], axis=0)
+        x = self._stream(jnp.take(params["embed"], batch["token"], axis=0))
         positions = batch["pos"]
         x = shard_act(x, ("act_batch", "act_embed"))
 
@@ -343,7 +355,7 @@ class LM:
         else:
             x, new_cache = jax.lax.scan(period_body, x,
                                         (params["slots"], cache["slots"]))
-        x = rms_norm(x, params["final_norm"], c.norm_eps)
+        x = self._compute(rms_norm(x, params["final_norm"], c.norm_eps))
         return self.logits(params, x), {"slots": new_cache}
 
     # ------------------------------------------------------------------

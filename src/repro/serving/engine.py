@@ -41,6 +41,18 @@ def _bucket(n: int) -> int:
     return b
 
 
+def _refusal(req: Request, cfg: FunctionConfig) -> str:
+    """Why ``req`` cannot be served, or "". A prompt is zero-padded to its
+    bucket, and in a model with recurrent layers the pads would run through
+    the recurrence and change the state every later token reads: such a
+    model takes only prompts whose length is a bucket."""
+    if _bucket(req.size) == req.size or not get_config(cfg.arch).recurrent:
+        return ""
+    return (f"{cfg.arch} has recurrent layers and cannot serve a prompt of "
+            f"{req.size} tokens zero-padded to {_bucket(req.size)}: it needs "
+            f"per-sequence prompt lengths (ROADMAP 2.1)")
+
+
 # "image layer cache": the same function image (arch, slots) yields the same
 # weights and compiled programs — first pull pays the full compile cold start,
 # replica instances hit the cache (exactly a container image/layer cache).
@@ -114,6 +126,7 @@ class Instance:
         self._fed = self._active = None
         self.decode_calls = 0
         self.feedback_uploads = 0
+        self.state_bytes = self.kv.state_bytes      # one slot's, by kind
         self._slot_meta: Dict[int, object] = {}
         self.generated: Dict[int, list] = {}         # rid -> token ids
 
@@ -189,6 +202,10 @@ class Worker:
             while self.pending:
                 p = self.pending.popleft()
                 cfg = self.store.get(p.req.fn)
+                refusal = _refusal(p.req, cfg)
+                if refusal:
+                    results.append(self._refused(p, refusal))
+                    continue
                 if not self._has_room(cfg):
                     still.append(p)
                     continue
@@ -233,6 +250,13 @@ class Worker:
         p._telemetry_idx = len(self.telemetry) - 1
         p._cold = cold
         inst._slot_meta[slot] = p
+
+    def _refused(self, p: _Pending, error: str) -> RequestResult:
+        now = time.monotonic()
+        return RequestResult(
+            rid=p.req.rid, fn=p.req.fn, ok=False, arrival_t=p.submit_t,
+            start_t=now, finish_t=now, cold_start=False, worker=self.name,
+            instance="", error=error)
 
     def _decode_instance(self, inst: Instance):
         """One decode step over every slot of ``inst``: one program, which

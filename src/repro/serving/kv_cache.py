@@ -1,17 +1,27 @@
-"""Slot-pool KV cache for continuous batching.
+"""Slot-pool decode cache for continuous batching.
 
 One :class:`SlotCache` backs one function instance: a decode cache of width
 ``slots`` on the batch dim (the within-instance concurrency), with per-slot
 insert (admission after prefill) and a shared decode step over all slots.
 Inactive slots decode garbage that is never read — standard continuous
 batching semantics.
+
+A slot holds state of two kinds, which the model's cache specs tell apart:
+keys and values of attention layers (``kv``, a leaf with a sequence axis,
+``act_kv_seq``) and the recurrent state of Mamba layers (``recurrent``: the
+conv window and the SSM state, a fixed size per slot).
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
+
+KV, RECURRENT = "kv", "recurrent"
+SEQ_AXIS = "act_kv_seq"        # the logical axis of a KV leaf's positions
 
 
 class SlotCache:
@@ -19,7 +29,18 @@ class SlotCache:
         self.model = model
         self.slots = slots
         self.max_len = max_len
+        specs, axes = model.cache_specs(slots, max_len)
         self.cache = model.init_cache(slots, max_len)
+        # per leaf, in the order of jax.tree.leaves(self.cache): the axis a
+        # prompt's keys and values are padded along, None for recurrent state
+        self._seq_axis = [ax.index(SEQ_AXIS) if SEQ_AXIS in ax else None
+                          for ax in jax.tree.leaves(
+                              axes, is_leaf=lambda a: isinstance(a, tuple))]
+        # bytes of one slot's state, by kind
+        self.state_bytes = {KV: 0, RECURRENT: 0}
+        for spec, seq in zip(jax.tree.leaves(specs), self._seq_axis):
+            size = math.prod(spec.shape) * jnp.dtype(spec.dtype).itemsize
+            self.state_bytes[RECURRENT if seq is None else KV] += size // slots
         self.pos = np.zeros(slots, np.int32)           # next position per slot
         self.active = np.zeros(slots, bool)
         self.rid = np.full(slots, -1, np.int64)
@@ -34,20 +55,21 @@ class SlotCache:
     def admit(self, slot: int, prefill_cache, prompt_len: int, rid: int,
               decode_steps: int):
         """Insert a prefilled (batch=1) sequence into `slot`; it finishes after
-        ``decode_steps`` more decode steps."""
-        def insert(c, p):
-            # c: [K, slots, W, ...] or [K, slots, ...]; p batch dim = 1
-            if c.ndim >= 3 and p.shape[2] != c.shape[2] and p.ndim == c.ndim:
-                # attn cache: prefill width S0 <= W
-                return jax.lax.dynamic_update_slice_in_dim(
-                    c, jax.lax.dynamic_update_slice_in_dim(
-                        jnp.zeros_like(c[:, slot:slot + 1]), p.astype(c.dtype),
-                        0, axis=2),
-                    slot, axis=1)
-            return jax.lax.dynamic_update_slice_in_dim(
-                c, p.astype(c.dtype), slot, axis=1)
+        ``decode_steps`` more decode steps. A KV leaf takes the prompt's keys
+        and values zero-padded along its sequence axis; a recurrent leaf is
+        the slot's whole state and is replaced."""
+        def insert(c, p, seq):
+            # c: [K, slots, ...]; p: [K, 1, ...]
+            p = p.astype(c.dtype)
+            if seq is not None:
+                p = jax.lax.dynamic_update_slice_in_dim(
+                    jnp.zeros_like(c[:, slot:slot + 1]), p, 0, axis=seq)
+            return jax.lax.dynamic_update_slice_in_dim(c, p, slot, axis=1)
         with TraceAnnotation("engine.insert", rid=rid):
-            self.cache = jax.tree.map(insert, self.cache, prefill_cache)
+            leaves, tree = jax.tree.flatten(self.cache)
+            self.cache = jax.tree.unflatten(tree, [
+                insert(c, p, seq) for c, p, seq in
+                zip(leaves, jax.tree.leaves(prefill_cache), self._seq_axis)])
             self.pos[slot] = prompt_len
             self.active[slot] = True
             self.rid[slot] = rid

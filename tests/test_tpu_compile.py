@@ -159,3 +159,22 @@ def test_image_params_draw_without_f32_copies(one_chip):
     assert params_bytes <= m.output_size_in_bytes < params_bytes + 2**20
     assert m.temp_size_in_bytes < 64 * 2**20
     assert _total_bytes(compiled) < HBM_BYTES
+
+
+def _falcon_mamba(layers: int):
+    """Falcon-Mamba-7B at every published width, cut in depth."""
+    cfg = dataclasses.replace(get_config("falcon_mamba_7b"), num_layers=layers)
+    return build_model(cfg)
+
+
+@pytest.mark.parametrize("program,seq", [("prefill", 128), ("prefill", 2048),
+                                         ("decode", 0)])
+def test_falcon_mamba_program_fits_one_chip(one_chip, program, seq):
+    """48 of 64 layers, 8 slots: the depth and slots the benchmark serves.
+    The weights (11.2 GB), the slots' state and the prefill's chunked scan
+    fit one chip, with room for the second instance's state."""
+    model = _falcon_mamba(48)
+    compiled = _compile(model, one_chip, program, seq=seq, slots=8)
+    assert _total_bytes(compiled) < 0.9 * HBM_BYTES
+    scope = "mamba.scan" if program == "prefill" else "mamba.step"
+    assert scope in compiled.as_text()        # the named scopes reach the HLO
