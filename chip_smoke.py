@@ -262,17 +262,18 @@ def kernels(s: KernelShapes, *, impl: str, seed: int) -> dict:
         jax.jit(functools.partial(ops.decode_attention, impl=impl))(q, kc, vc, pos),
         jax.jit(ref.decode_attention_ref)(q, kc, vc, pos))
 
-    dt = (jax.nn.softplus(normal((1, s.scan_len, s.d_inner), jnp.float32))
-          * 0.1).astype(bf)
+    # as a prefill calls it: dt, B, C, A in f32, x and D in bf16
+    dt = jax.nn.softplus(normal((1, s.scan_len, s.d_inner), jnp.float32)) * 0.1
     x = normal((1, s.scan_len, s.d_inner))
-    B = normal((1, s.scan_len, s.d_state))
-    C = normal((1, s.scan_len, s.d_state))
+    B = normal((1, s.scan_len, s.d_state), jnp.float32)
+    C = normal((1, s.scan_len, s.d_state), jnp.float32)
     A = -jnp.exp(normal((s.d_inner, s.d_state), jnp.float32) * 0.2)
-    D = normal((s.d_inner,), jnp.float32)
-    errs["mamba_scan"] = _check(
-        "mamba_scan",
+    D = normal((s.d_inner,))
+    (y, h), (y_ref, h_ref) = (
         jax.jit(functools.partial(ops.mamba_scan, impl=impl))(dt, x, B, C, A, D),
         jax.jit(ref.mamba_scan_ref)(dt, x, B, C, A, D))
+    errs["mamba_scan"] = max(_check("mamba_scan", y, y_ref),
+                             _check("mamba_scan final state", h, h_ref))
 
     block_t = 128
     xs = normal((s.rows, s.d_model))

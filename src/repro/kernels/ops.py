@@ -7,8 +7,9 @@
 * "ref"       — pure-jnp oracle
 
 The caller's ``impl`` is the mode that runs; nothing overrides it. The model
-layer calls these when constructed with ``attn_impl="pallas"`` or
-``"interpret"``.
+layer calls the attention kernels when constructed with ``attn_impl="pallas"``
+or ``"interpret"``, and ``mamba_scan`` in every Mamba prefill on a TPU
+(``models/mamba.py``).
 """
 from __future__ import annotations
 
@@ -46,6 +47,7 @@ def decode_attention(q, k_cache, v_cache, positions, *, ring=False,
 
 
 def mamba_scan(dt, x, B, C, A, D, *, impl="interpret"):
+    """-> (y [Bt, S, DI] f32, final state [Bt, DI, N] f32)."""
     if impl == "ref":
         return _ref.mamba_scan_ref(dt, x, B, C, A, D)
     return _mamba_pallas(dt, x, B, C, A, D, interpret=_interpret(impl))

@@ -17,21 +17,23 @@ def decode_attention_ref(q, k_cache, v_cache, positions, *, ring=False):
 
 
 def mamba_scan_ref(dt, x, B, C, A, D):
-    """Sequential recurrence in f64-ish f32. Shapes as kernel wrapper."""
+    """Sequential recurrence in f32 from a zero state, one step per position.
+    Shapes as the kernel wrapper's; returns (y [Bt, S, DI], h_S [Bt, DI, N])."""
     Bt, S, DI = x.shape
-    a = jnp.exp(dt.astype(jnp.float32)[..., None] * A.astype(jnp.float32))
-    bx = (dt * x).astype(jnp.float32)[..., None] * B.astype(jnp.float32)[:, :, None, :]
+    f32 = jnp.float32
+    dt, x = dt.astype(f32), x.astype(f32)
+    a = jnp.exp(dt[..., None] * A.astype(f32))
+    bx = (dt * x)[..., None] * B.astype(f32)[:, :, None, :]
 
     def step(h, inp):
         at, bxt, ct = inp
         h = at * h + bxt
         return h, jnp.sum(h * ct[:, None, :], axis=-1)
 
-    h0 = jnp.zeros((Bt, DI, A.shape[1]), jnp.float32)
-    _, ys = jax.lax.scan(step, h0, (a.swapaxes(0, 1), bx.swapaxes(0, 1),
-                                    C.astype(jnp.float32).swapaxes(0, 1)))
-    y = ys.swapaxes(0, 1) + x.astype(jnp.float32) * D.astype(jnp.float32)
-    return y.astype(x.dtype)
+    h0 = jnp.zeros((Bt, DI, A.shape[1]), f32)
+    h_last, ys = jax.lax.scan(step, h0, (a.swapaxes(0, 1), bx.swapaxes(0, 1),
+                                         C.astype(f32).swapaxes(0, 1)))
+    return ys.swapaxes(0, 1) + x * D.astype(f32), h_last
 
 
 def grouped_matmul_ref(x, w, block_to_expert, block_t):

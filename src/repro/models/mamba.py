@@ -1,11 +1,14 @@
 """Mamba-1 (selective SSM) block — falcon-mamba and the jamba hybrid.
 
-Sequence mode uses a *chunked* scan: ``lax.scan`` over chunks carrying the
-SSM state, with a numerically-stable ``lax.associative_scan`` inside each
-chunk — the state tensor [B, chunk, d_inner, d_state] never exceeds one chunk
-(a full-sequence associative scan at 32k × 8192 × 16 would be ~17 GB/device).
-This mirrors the VMEM-chunked structure of the Pallas kernel in
-``repro.kernels.mamba_scan``.
+Sequence mode runs the selective scan on one of two paths. A prefill on a
+TPU (the caller wants the final state, ``want_cache``) runs the fused Pallas
+kernel ``repro.kernels.mamba_scan``, whose [N, block] state never leaves
+VMEM. Everywhere else (training, which needs the gradient the kernel lacks;
+the dry run, whose cost analysis does not count a kernel; every CPU run) it
+runs ``_ssm_chunk_scan`` in XLA: ``lax.scan`` over chunks carrying the SSM
+state, with a numerically-stable ``lax.associative_scan`` inside each chunk —
+the state tensor [B, chunk, d_inner, d_state] never exceeds one chunk (a
+full-sequence associative scan at 32k × 8192 × 16 would be ~17 GB/device).
 
 Decode mode is the O(1) recurrence: one state update per token; the "cache"
 is (conv ring window, SSM state) — constant in sequence length, which is why
@@ -19,11 +22,12 @@ view and in HLO dumps.
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
 from repro.models.layers import shard_act
 
 
@@ -39,8 +43,8 @@ def _ssm_chunk_scan(dt: jax.Array, xi: jax.Array, Bc: jax.Array, Cc: jax.Array,
     a/bx/h are built PER CHUNK inside the scan and y is contracted against C
     before the next chunk — the [B, S, DI, N] tensors never exist at full
     sequence length (an 88-layer jamba prefill materializing them measured
-    198 GiB/device; fused: chunk-sized only). Mirrors the Pallas kernel's
-    VMEM blocking (repro.kernels.mamba_scan).
+    198 GiB/device; fused: chunk-sized only). Each chunk's tensors still go
+    through HBM, which is why a prefill on a TPU takes the Pallas kernel.
     """
     B, S, DI = xi.shape
     chunk = min(chunk, S)
@@ -102,11 +106,24 @@ def _selection(xi: jax.Array, p: dict, m):
     return dt, Bc, Cc
 
 
+def _scan_path(want_cache: bool, unroll: bool) -> str:
+    """The fused kernel for a prefill (the final state wanted, scans not
+    unrolled for cost counting) on a TPU backend; XLA's chunked scan else."""
+    if want_cache and not unroll and jax.default_backend() == "tpu":
+        return "pallas"
+    return "xla"
+
+
 def mamba_forward(x: jax.Array, p: dict, cfg, *, chunk: int = 256,
-                  unroll: bool = False) -> Tuple[jax.Array, dict]:
-    """Sequence mode. x: [B, S, D] -> (y [B, S, D], cache {conv, ssm})."""
+                  unroll: bool = False, want_cache: bool = False,
+                  _scan: Optional[str] = None) -> Tuple[jax.Array, dict]:
+    """Sequence mode. x: [B, S, D] -> (y [B, S, D], cache {conv, ssm}).
+
+    ``_scan`` ("pallas", "interpret" or "xla") fixes the scan's path in
+    place of :func:`_scan_path`, for tests."""
     m = cfg.mamba
     B, S, D = x.shape
+    f32 = jnp.float32
     xi = jnp.einsum("bsd,de->bse", x, p["in_x"])         # [B, S, DI]
     z = jnp.einsum("bsd,de->bse", x, p["in_z"])
     xi = shard_act(xi, ("act_batch", "act_seq", "act_mlp"))
@@ -115,17 +132,21 @@ def mamba_forward(x: jax.Array, p: dict, cfg, *, chunk: int = 256,
     xi = jax.nn.silu(xi + p["conv_b"])
 
     dt, Bc, Cc = _selection(xi, p, m)                    # dt [B, S, DI]
-    A = -jnp.exp(p["A_log"].astype(jnp.float32))                      # [DI, N]
+    A = -jnp.exp(p["A_log"].astype(f32))                 # [DI, N]
+    path = _scan or _scan_path(want_cache, unroll)
     with jax.named_scope("mamba.scan"):
-        y, h_last = _ssm_chunk_scan(dt, xi.astype(jnp.float32),
-                                    Bc.astype(jnp.float32),
-                                    Cc.astype(jnp.float32), A,
-                                    jnp.zeros((B, m.d_inner, m.d_state),
-                                              jnp.float32), chunk, unroll)
-    y = (y + xi.astype(jnp.float32) * p["D"].astype(jnp.float32)) \
-        * jax.nn.silu(z.astype(jnp.float32))
+        if path == "xla":
+            y, h_last = _ssm_chunk_scan(dt, xi.astype(f32), Bc.astype(f32),
+                                        Cc.astype(f32), A,
+                                        jnp.zeros((B, m.d_inner, m.d_state), f32),
+                                        chunk, unroll)
+            y = y + xi.astype(f32) * p["D"].astype(f32)
+        else:                   # the kernel adds D x itself
+            y, h_last = ops.mamba_scan(dt, xi, Bc.astype(f32), Cc.astype(f32),
+                                       A, p["D"], impl=path)
+    y = y * jax.nn.silu(z.astype(f32))
     out = jnp.einsum("bse,ed->bsd", y.astype(x.dtype), p["out_proj"])
-    return out, {"conv": conv_carry, "ssm": h_last.astype(jnp.float32)}
+    return out, {"conv": conv_carry, "ssm": h_last}
 
 
 def mamba_decode(x: jax.Array, p: dict, cfg, cache: dict) -> Tuple[jax.Array, dict]:

@@ -191,7 +191,7 @@ class LM:
     # ------------------------------------------------------------------
     # Blocks
     # ------------------------------------------------------------------
-    def _block_seq(self, x, p, sk: SlotKind, positions):
+    def _block_seq(self, x, p, sk: SlotKind, positions, want_cache: bool):
         c = self.cfg
         h = self._compute(rms_norm(x, p["norm1"], c.norm_eps))
         if sk.kind == BLOCK_ATTN:
@@ -200,7 +200,8 @@ class LM:
                 block=self.attn_block, impl=self.attn_impl, unroll=self.unroll)
         else:
             h, cache = mamba_mod.mamba_forward(h, p, c, chunk=self.mamba_chunk,
-                                               unroll=self.unroll)
+                                               unroll=self.unroll,
+                                               want_cache=want_cache)
         x = x + h
         x = shard_act(x, ("act_batch", "act_seq", "act_embed"))
         if sk.is_moe:
@@ -254,7 +255,8 @@ class LM:
             xc = jax.lax.optimization_barrier(xc)
             caches = []
             for s, sk in enumerate(self.slots):
-                xc, cache = self._block_seq(xc, slot_params[s], sk, positions)
+                xc, cache = self._block_seq(xc, slot_params[s], sk, positions,
+                                            want_cache)
                 caches.append(cache if want_cache else jnp.zeros((), x.dtype))
             return xc, caches
 

@@ -149,6 +149,46 @@ def test_mamba_decode_matches_forward_step_by_step(rng, eps):
                                    rtol=1e-4, atol=1e-4)
 
 
+def test_mamba_kernel_path_matches_chunked_path(rng):
+    """The prefill's scan in the Pallas kernel (interpret mode) gives the
+    chunked XLA scan's output and {conv, ssm} state (f32, tiny widths)."""
+    from repro.models import mamba as mamba_mod
+    cfg = _prep("falcon_mamba_7b", "float32")
+    p = jax.tree.map(lambda a: a[0], build_model(cfg).init_params(rng)["slots"][0])
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 40, cfg.d_model), jnp.float32)
+    got = mamba_mod.mamba_forward(x, p, cfg, want_cache=True, _scan="interpret")
+    want = mamba_mod.mamba_forward(x, p, cfg, chunk=16, want_cache=True, _scan="xla")
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), got) == \
+        jax.tree.map(lambda a: (a.shape, a.dtype), want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4, atol=2e-4)
+
+
+def test_only_prefill_on_a_tpu_runs_the_scan_kernel(monkeypatch):
+    """Lowered for a TPU, only ``LM.prefill`` with a TPU backend holds the
+    Pallas scan: not the forward without its cache, the loss or its
+    gradient (training), the dry run's unrolled prefill, or a prefill on
+    another backend."""
+    cfg = replace(reduced(get_config("falcon_mamba_7b")), dtype="bfloat16")
+    m = build_model(cfg)
+    params = jax.eval_shape(m.init_params, jax.random.PRNGKey(0))
+    tok = jax.ShapeDtypeStruct((1, 32), jnp.int32)
+
+    def kernel_in(fn, batch):
+        lowered = jax.jit(fn).trace(params, batch).lower(lowering_platforms=("tpu",))
+        return "tpu_custom_call" in lowered.as_text()
+
+    assert not kernel_in(m.prefill, {"tokens": tok})           # a CPU backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert kernel_in(m.prefill, {"tokens": tok})
+    assert not kernel_in(lambda p, b: m.forward_seq(p, b, want_cache=False)[0],
+                         {"tokens": tok})
+    train = {"tokens": tok, "labels": tok}
+    assert not kernel_in(lambda p, b: m.loss_fn(p, b)[0], train)
+    assert not kernel_in(jax.grad(lambda p, b: m.loss_fn(p, b)[0]), train)
+    assert not kernel_in(build_model(cfg, unroll=True).prefill, {"tokens": tok})
+
+
 def test_float32_residual_stream(rng):
     """Falcon-Mamba publishes ``residual_in_fp32``: in a bfloat16 model the
     stream between layers stays float32, so prefill and decode logits lie

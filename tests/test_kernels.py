@@ -66,8 +66,14 @@ def test_decode_attention_sweep(B, W, H, KV, hd, ring, dtype, rng):
     (2, 128, 64, 8, 32, 32),
     (1, 64, 128, 16, 64, 64),
     (2, 96, 32, 4, 32, 16),
+    (1, 8, 128, 16, 64, 64),          # S below one chunk
+    (2, 48, 256, 16, 16, 128),        # three chunks, two d_inner blocks
 ])
 def test_mamba_scan_sweep(B, S, DI, N, chunk, bd, dtype, rng):
+    """y and the final state against the sequential oracle and against the
+    model's chunked XLA scan, at float32 tolerance: every operand is cast to
+    float32 before the recurrence, so the input dtype does not widen it."""
+    from repro.models.mamba import _ssm_chunk_scan
     ks = jax.random.split(rng, 6)
     dt = (jax.nn.softplus(jax.random.normal(ks[0], (B, S, DI))) * 0.1).astype(dtype)
     x = jax.random.normal(ks[1], (B, S, DI), dtype)
@@ -75,13 +81,15 @@ def test_mamba_scan_sweep(B, S, DI, N, chunk, bd, dtype, rng):
     Cc = jax.random.normal(ks[3], (B, S, N), dtype)
     A = -jnp.exp(jax.random.normal(ks[4], (DI, N)) * 0.2)
     D = jax.random.normal(ks[5], (DI,))
-    y = mamba_scan(dt, x, Bc, Cc, A, D, chunk=chunk, block_d=bd,
-                   interpret=True)
-    ref = R.mamba_scan_ref(dt, x, Bc, Cc, A, D)
-    tol = dict(rtol=5e-2, atol=5e-2) if dtype == jnp.bfloat16 \
-        else dict(rtol=2e-3, atol=2e-3)
-    np.testing.assert_allclose(np.asarray(y, np.float32),
-                               np.asarray(ref, np.float32), **tol)
+    y, h = mamba_scan(dt, x, Bc, Cc, A, D, chunk=chunk, block_d=bd,
+                      interpret=True)
+    assert y.dtype == h.dtype == jnp.float32 and h.shape == (B, DI, N)
+    f32 = [a.astype(jnp.float32) for a in (dt, x, Bc, Cc)]
+    y_xla, h_xla = _ssm_chunk_scan(*f32, A, jnp.zeros((B, DI, N)), chunk)
+    for want_y, want_h in (R.mamba_scan_ref(dt, x, Bc, Cc, A, D),
+                           (y_xla + f32[1] * D, h_xla)):
+        np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), **_tol(jnp.float32))
+        np.testing.assert_allclose(np.asarray(h), np.asarray(want_h), **_tol(jnp.float32))
 
 
 @pytest.mark.slow

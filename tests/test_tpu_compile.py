@@ -108,12 +108,14 @@ def _kernel_case(name, sh):
                 [_spec(sh, (4, H, hd)), _spec(sh, (4, 4096, KV, hd)),
                  _spec(sh, (4, 4096, KV, hd)), _spec(sh, (4,), jnp.int32)])
     if name == "mamba_scan":
-        DI, N = m.d_inner, m.d_state
+        # as a Falcon-Mamba prefill calls it at the largest bucket: dt, B, C
+        # and A in f32, x and D in the model's bf16; y and the final state out
+        DI, N, S, f32 = m.d_inner, m.d_state, 2048, jnp.float32
         return (lambda dt, x, B, C, A, D: ops.mamba_scan(dt, x, B, C, A, D,
                                                          impl="pallas"),
-                [_spec(sh, (1, 512, DI)), _spec(sh, (1, 512, DI)),
-                 _spec(sh, (1, 512, N)), _spec(sh, (1, 512, N)),
-                 _spec(sh, (DI, N), jnp.float32), _spec(sh, (DI,), jnp.float32)])
+                [_spec(sh, (1, S, DI), f32), _spec(sh, (1, S, DI)),
+                 _spec(sh, (1, S, N), f32), _spec(sh, (1, S, N), f32),
+                 _spec(sh, (DI, N), f32), _spec(sh, (DI,))])
     assert name == "grouped_matmul"
     return (lambda x, w, b: ops.grouped_matmul(x, w, b, impl="pallas"),
             [_spec(sh, (1024, c.d_model)), _spec(sh, (8, c.d_model, 2048)),
@@ -169,12 +171,16 @@ def _falcon_mamba(layers: int):
 
 @pytest.mark.parametrize("program,seq", [("prefill", 128), ("prefill", 2048),
                                          ("decode", 0)])
-def test_falcon_mamba_program_fits_one_chip(one_chip, program, seq):
+def test_falcon_mamba_program_fits_one_chip(one_chip, program, seq, monkeypatch):
     """48 of 64 layers, 8 slots: the depth and slots the benchmark serves.
-    The weights (11.2 GB), the slots' state and the prefill's chunked scan
-    fit one chip, with room for the second instance's state."""
+    The weights (11.2 GB), the slots' state and the prefill's scan fit one
+    chip, with room for the second instance's state. The prefill is the
+    served one, with the Pallas scan that a TPU backend chooses."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     model = _falcon_mamba(48)
     compiled = _compile(model, one_chip, program, seq=seq, slots=8)
     assert _total_bytes(compiled) < 0.9 * HBM_BYTES
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (program == "prefill")
     scope = "mamba.scan" if program == "prefill" else "mamba.step"
-    assert scope in compiled.as_text()        # the named scopes reach the HLO
+    assert scope in text                      # the named scopes reach the HLO
